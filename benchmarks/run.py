@@ -80,6 +80,8 @@ def main() -> int:
                             bench_health, bench_hotpath, bench_paged_attn,
                             bench_profile, bench_pushdown, bench_rebuild,
                             bench_toolchain, roofline, trajectory)
+    from repro.runtime import place_compile_cache
+    place_compile_cache()
 
     suites = {
         "filter": lambda: bench_filter.main(

@@ -29,7 +29,6 @@ from typing import Callable, Optional
 import numpy as np
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 
 from repro.core.programs import (
@@ -38,6 +37,7 @@ from repro.core.programs import (
     OpCode,
     Program,
 )
+from repro.runtime import offload_x64
 
 __all__ = [
     "OffloadResult",
@@ -302,7 +302,7 @@ class JittedProgram:
         # the executable was compiled under 64-bit mode; the call must run
         # under it too, or device_put canonicalizes int64/float64 zone pages
         # down to 32 bits and the input aval check rejects them
-        with jax.experimental.enable_x64():
+        with offload_x64():
             return self.fn(pages)
 
 
@@ -426,7 +426,7 @@ def jit_program(
     t0 = time.perf_counter()
     # int64 accumulators need 64-bit mode at *trace* time; scope it to the
     # offload compiler so the model stack keeps JAX's 32-bit defaults.
-    with jax.experimental.enable_x64():
+    with offload_x64():
         jitted = jax.jit(run, donate_argnums=(0,) if donate else ())
         compiled = jitted.lower(spec).compile()
     compile_seconds = time.perf_counter() - t0
@@ -450,7 +450,7 @@ def jit_program_batched(
     run = _build_program_runner(program)
     spec = jax.ShapeDtypeStruct((n_chunks, n_pages, page_elems), dtype)
     t0 = time.perf_counter()
-    with jax.experimental.enable_x64():
+    with offload_x64():
         compiled = jax.jit(jax.vmap(run)).lower(spec).compile()
     compile_seconds = time.perf_counter() - t0
     return JittedProgram(compiled, compile_seconds, n_pages, page_elems, program)

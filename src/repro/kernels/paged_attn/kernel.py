@@ -20,11 +20,14 @@ discipline as zone_filter: one zone block (ZL x KV x hd) in VMEM at a time.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import pallas_interpret
 
 __all__ = ["paged_attention_pallas"]
 
@@ -71,10 +74,13 @@ def _decode_kernel(ztab_ref, len_ref, q_ref, k_ref, v_ref, out_ref,
 
 
 def paged_attention_pallas(q, k_zones, v_zones, zone_table, lengths, *,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: [B, H, hd]; k_zones/v_zones: [NZ, ZL, KV, hd];
     zone_table: [B, MZ] int32 (-1 = unused); lengths: [B] int32.
-    Returns [B, H, hd]."""
+    Returns [B, H, hd]. ``interpret`` defaults to
+    :func:`repro.runtime.pallas_interpret`."""
+    if interpret is None:
+        interpret = pallas_interpret()
     B, H, hd = q.shape
     NZ, ZL, KV, _ = k_zones.shape
     MZ = zone_table.shape[1]

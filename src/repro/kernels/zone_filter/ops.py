@@ -6,11 +6,11 @@ import functools
 import time
 
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.programs import CMP_OPS, OpCode, Program
+from repro.runtime import offload_x64
 from repro.kernels.zone_filter.kernel import (
     filtered_reduce_pallas,
     filtered_reduce_pallas_batched,
@@ -66,40 +66,45 @@ def _program_transform(program: Program):
                     OpCode.ADD: lambda: x + immt, OpCode.SUB: lambda: x - immt,
                     OpCode.MUL: lambda: x * immt, OpCode.AND: lambda: x & immt,
                     OpCode.OR: lambda: x | immt, OpCode.XOR: lambda: x ^ immt,
-                    OpCode.SHL: lambda: x << imm, OpCode.SHR: lambda: x >> imm,
+                    OpCode.SHL: lambda: x << immt, OpCode.SHR: lambda: x >> immt,
                     OpCode.MOD: lambda: x % immt,
                 }[op]()
         return x, mask
     return transform
 
 
-@functools.partial(jax.jit, static_argnames=("threshold", "interpret",
-                                             "block_pages"))
-def zone_filter_count(pages, threshold, *, interpret: bool = True,
-                      block_pages: int = 512):
+@functools.partial(jax.jit, static_argnames=("threshold", "block_pages"))
+def zone_filter_count(pages, threshold, *, block_pages: int = 512):
     """The paper's workload: count zone elements above threshold."""
     thr = threshold
     return filtered_reduce_pallas(
         pages, kind="count",
         transform=lambda x: (x, x > jnp.asarray(thr, x.dtype)),
-        block_pages=block_pages, interpret=interpret)
+        block_pages=block_pages)
 
 
-@functools.partial(jax.jit, static_argnames=("kind", "threshold", "interpret",
+@functools.partial(jax.jit, static_argnames=("kind", "threshold",
                                              "block_pages"))
 def zone_reduce(pages, kind: str = "count", threshold=None, *,
-                interpret: bool = True, block_pages: int = 512):
+                block_pages: int = 512):
     if threshold is None:
         transform = None
     else:
         thr = threshold
         transform = lambda x: (x, x > jnp.asarray(thr, x.dtype))
     return filtered_reduce_pallas(pages, kind=kind, transform=transform,
-                                  block_pages=block_pages, interpret=interpret)
+                                  block_pages=block_pages)
 
 
-def run_program_kernel(program: Program, pages: np.ndarray, *,
-                       interpret: bool = True):
+def _program_kernel(program: Program, kernel):
+    """``kernel`` specialised to a verified, kernelizable ``program``."""
+    if not kernelizable(program):
+        raise ValueError(f"program {program.name} is not kernelizable")
+    return functools.partial(kernel, kind=_TERM_KIND[program.terminal.op],
+                             transform=_program_transform(program))
+
+
+def run_program_kernel(program: Program, pages: np.ndarray):
     """Execute a verified Program on the Pallas tier (the CSD 'hardware
     backend'). Caller guarantees kernelizable(program).
 
@@ -107,72 +112,44 @@ def run_program_kernel(program: Program, pages: np.ndarray, *,
     :func:`kernel_program` so the compiled executable lands in the shared
     :class:`~repro.core.cache.CompiledProgramCache`.
     """
-    if not kernelizable(program):
-        raise ValueError(f"program {program.name} is not kernelizable")
-    kind = _TERM_KIND[program.terminal.op]
-    transform = _program_transform(program)
-    fn = jax.jit(functools.partial(
-        filtered_reduce_pallas, kind=kind, transform=transform,
-        interpret=interpret))
+    fn = jax.jit(_program_kernel(program, filtered_reduce_pallas))
     return fn(jnp.asarray(pages))
 
 
-def run_program_kernel_batched(program: Program, pages: np.ndarray, *,
-                               interpret: bool = True):
+def run_program_kernel_batched(program: Program, pages: np.ndarray):
     """Chunk-batched Pallas execution: ``pages[n_chunks, n_pages, page_elems]``
     -> per-chunk reduced values ``[n_chunks]`` from ONE grid-batched kernel
     call (leading grid dimension over the chunk axis)."""
-    if not kernelizable(program):
-        raise ValueError(f"program {program.name} is not kernelizable")
-    kind = _TERM_KIND[program.terminal.op]
-    transform = _program_transform(program)
-    fn = jax.jit(functools.partial(
-        filtered_reduce_pallas_batched, kind=kind, transform=transform,
-        interpret=interpret))
+    fn = jax.jit(_program_kernel(program, filtered_reduce_pallas_batched))
     return fn(jnp.asarray(pages))
 
 
-def _aot_compile(run, spec):
-    """AOT lower+compile with the paper's 'JIT time' measured; traced under
-    64-bit mode like the XLA JIT tier so int64/float64 zone dtypes keep their
-    verified semantics."""
-    t0 = time.perf_counter()
-    with jax.experimental.enable_x64():
-        compiled = jax.jit(run).lower(spec).compile()
-    return compiled, time.perf_counter() - t0
-
-
-def kernel_program(program: Program, n_pages: int, page_elems: int, *,
-                   interpret: bool = True):
-    """Compile a verified Program to a shaped Pallas executable, returned as a
-    :class:`~repro.core.vm.JittedProgram` (so the kernel tier reports compile
-    time and caches exactly like the XLA JIT tier)."""
+def _aot_compile(program: Program, kernel, shape: tuple[int, ...]):
+    """AOT lower+compile of ``kernel`` for ``program`` at ``shape``, returned
+    as a :class:`~repro.core.vm.JittedProgram` (so the kernel tier reports
+    the paper's 'JIT time' and caches exactly like the XLA JIT tier). Traced
+    under the offload 64-bit scope like the XLA JIT tier so int64/float64
+    zone dtypes keep their verified semantics."""
     from repro.core.vm import JittedProgram  # local: keep import DAG one-way
-    if not kernelizable(program):
-        raise ValueError(f"program {program.name} is not kernelizable")
-    kind = _TERM_KIND[program.terminal.op]
-    transform = _program_transform(program)
-    run = functools.partial(filtered_reduce_pallas, kind=kind,
-                            transform=transform, interpret=interpret)
-    dtype = np.dtype(program.input_dtype)
-    spec = jax.ShapeDtypeStruct((n_pages, page_elems), dtype)
-    compiled, compile_seconds = _aot_compile(run, spec)
-    return JittedProgram(compiled, compile_seconds, n_pages, page_elems, program)
+    run = _program_kernel(program, kernel)
+    spec = jax.ShapeDtypeStruct(shape, np.dtype(program.input_dtype))
+    t0 = time.perf_counter()
+    with offload_x64():
+        compiled = jax.jit(run).lower(spec).compile()
+    return JittedProgram(compiled, time.perf_counter() - t0, shape[-2],
+                         shape[-1], program)
+
+
+def kernel_program(program: Program, n_pages: int, page_elems: int):
+    """Compile a verified Program to a shaped Pallas executable."""
+    return _aot_compile(program, filtered_reduce_pallas,
+                        (n_pages, page_elems))
 
 
 def kernel_program_batched(program: Program, n_chunks: int, n_pages: int,
-                           page_elems: int, *, interpret: bool = True):
+                           page_elems: int):
     """Compile the chunk-batched Pallas kernel for a fixed
     ``[n_chunks, n_pages, page_elems]`` geometry (the scheduler's striped
     fan-out shape)."""
-    from repro.core.vm import JittedProgram
-    if not kernelizable(program):
-        raise ValueError(f"program {program.name} is not kernelizable")
-    kind = _TERM_KIND[program.terminal.op]
-    transform = _program_transform(program)
-    run = functools.partial(filtered_reduce_pallas_batched, kind=kind,
-                            transform=transform, interpret=interpret)
-    dtype = np.dtype(program.input_dtype)
-    spec = jax.ShapeDtypeStruct((n_chunks, n_pages, page_elems), dtype)
-    compiled, compile_seconds = _aot_compile(run, spec)
-    return JittedProgram(compiled, compile_seconds, n_pages, page_elems, program)
+    return _aot_compile(program, filtered_reduce_pallas_batched,
+                        (n_chunks, n_pages, page_elems))

@@ -115,13 +115,11 @@ class KVZonePool:
             lengths[i] = st.length
         return jnp.asarray(tab), jnp.asarray(lengths)
 
-    def attend(self, seq_ids: list[int], q: jnp.ndarray, *,
-               interpret: bool = True) -> jnp.ndarray:
+    def attend(self, seq_ids: list[int], q: jnp.ndarray) -> jnp.ndarray:
         """q: [B, H, head_dim] (B == len(seq_ids)). Flash-decode over the
         zone pool via the Pallas kernel."""
         tab, lengths = self.zone_table(seq_ids)
-        return paged_attention(q, self.k, self.v, tab, lengths,
-                               interpret=interpret)
+        return paged_attention(q, self.k, self.v, tab, lengths)
 
     def utilization(self) -> float:
         used = self.num_zones - len(self._free)

@@ -73,13 +73,7 @@ def pipeline_apply(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis_name), stage_params)
-    if hasattr(jax, "shard_map"):                      # jax >= 0.6
-        smap = jax.shard_map(
-            run, mesh=mesh, in_specs=(spec_params, P()), out_specs=P(),
-            check_vma=False)
-    else:                                              # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(
-            run, mesh=mesh, in_specs=(spec_params, P()), out_specs=P(),
-            check_rep=False)
+    smap = jax.shard_map(
+        run, mesh=mesh, in_specs=(spec_params, P()), out_specs=P(),
+        check_vma=False)
     return jax.jit(smap)(stage_params, xs)
