@@ -42,6 +42,7 @@ A 1-device array degrades to the ``NvmCsd`` semantics — the degenerate path.
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from dataclasses import dataclass, field
@@ -611,9 +612,9 @@ class OffloadScheduler:
             self._dispatch_io(cmd, pair)
             return True
         try:
-            with _trace.span("offload.execute", tenant=cmd.tenant,
-                             tier=cmd.tier, zone=cmd.zone_id,
-                             program=cmd.program.name):
+            with _trace.span("offload.execute", offload=cmd.cmd_id,
+                             tenant=cmd.tenant, tier=cmd.tier,
+                             zone=cmd.zone_id, program=cmd.program.name):
                 value, stats = self._execute(cmd)
             comp = Completion(cmd.cmd_id, cmd.tenant, value=value, stats=stats)
             self.history.append(stats)
@@ -647,19 +648,9 @@ class OffloadScheduler:
 
     @staticmethod
     def _publish_stats(stats: ArrayOffloadStats) -> None:
-        """Fold one command's ArrayOffloadStats into the global registry, so
-        ``metrics.registry().snapshot()`` shows the rolling offload picture
-        (commands, read/compute/overlap seconds, the latest overlap ratio)
-        next to the cache and gather-pool series."""
-        reg = _registry()
-        reg.counter("offload.commands").inc()
-        reg.counter("offload.dispatches").inc(stats.n_dispatches)
-        reg.histogram("offload.exec_seconds").observe(stats.exec_seconds)
-        reg.histogram("offload.read_seconds").observe(stats.read_seconds)
-        reg.histogram("offload.read_wait_seconds").observe(
-            stats.read_wait_seconds)
-        reg.histogram("offload.overlap_seconds").observe(stats.overlap_seconds)
-        reg.gauge("offload.overlap_ratio").set(stats.overlap_ratio)
+        """Count one completed offload on the global registry
+        (``offload.commands``, what per-offload readings divide by)."""
+        _registry().counter("offload.commands").inc()
 
     def _account_tenant(self, cmd: OffloadCommand, comp: Completion) -> None:
         """Per-tenant QoS accounting at completion time (offloads AND raw
@@ -1051,26 +1042,33 @@ class OffloadScheduler:
         its ring completion retires — on the gather pool, never the reactor
         thread — so staging memcpys hide under the remaining members'
         transfers and the previous group's dispatch instead of serializing
-        on the dispatcher's critical path."""
+        on the dispatcher's critical path. The ``stage.copy`` span encloses
+        the copy that ``sched.stage.staging_seconds`` sums."""
         def copy():
-            t0 = time.perf_counter()
-            try:
-                if run.fut.error is None:
-                    part = np.asarray(run.fut.value).reshape(
-                        len(run.items), chunk_pages, page_elems)
-                    for j, (row, _c) in enumerate(run.items):
-                        grp.pages[row] = part[j]
-            finally:
-                with grp.lock:
-                    grp.stage_s += time.perf_counter() - t0
-                    grp.pending -= 1
-                    if grp.pending == 0:
-                        grp.staged.set()
+            with _trace.span("stage.copy", device=run.device,
+                             rows=len(run.items)):
+                t0 = time.perf_counter()
+                try:
+                    if run.fut.error is None:
+                        part = np.asarray(run.fut.value).reshape(
+                            len(run.items), chunk_pages, page_elems)
+                        for j, (row, _c) in enumerate(run.items):
+                            grp.pages[row] = part[j]
+                finally:
+                    with grp.lock:
+                        grp.stage_s += time.perf_counter() - t0
+                        grp.pending -= 1
+                        if grp.pending == 0:
+                            grp.staged.set()
+        # The completion callback runs on the reactor, outside the offload:
+        # while tracing, hand the pool the offload's context from here.
+        ctx = contextvars.copy_context() if _trace.enabled() else None
         # Always hop to the gather pool: the callback fires inline on the
         # DISPATCHER thread when a short emulated transfer retires before
         # registration, and an inline memcpy there serializes all staging
         # into the read-submission loop — the exact cliff this stage hides.
-        run.fut.add_done_callback(lambda _f: _gather_executor().submit(copy))
+        run.fut.add_done_callback(
+            lambda _f: _gather_executor().submit(copy, ctx))
 
     # -------------------------------------------------------- compute stage
     def _compute_stage(self, cmd: OffloadCommand, staged: "_StagedReads",
@@ -1147,17 +1145,24 @@ class OffloadScheduler:
             reg.histogram("sched.stage.read_wait_seconds").observe(dt)
             if not served:
                 continue
-            with _trace.span("stage.staging", chunks=len(served)):
-                if grp.zero_copy:
-                    pages = np.asarray(raw0).reshape(m_b, chunk_pages,
-                                                     page_elems)
-                else:
-                    pages = grp.pages
+            if grp.zero_copy:
+                pages = np.asarray(raw0).reshape(m_b, chunk_pages, page_elems)
+            else:
+                pages = grp.pages
             agg.stage_s += grp.stage_s
             reg.histogram("sched.stage.staging_seconds").observe(grp.stage_s)
+            put = None
             t_d = time.perf_counter()
             with _trace.span("stage.dispatch", chunks=len(served)):
-                out = jp(pages)
+                if _trace.enabled():
+                    # the put the call would start itself, started here so
+                    # land() can record when it finished: the dispatcher
+                    # never waits for it
+                    put = [time.monotonic()]
+                    put.append(jp.put(pages))
+                    out = jp(put[1])
+                else:
+                    out = jp(pages)
             dt = time.perf_counter() - t_d
             agg.compute_s += dt
             agg.dispatches += 1
@@ -1171,8 +1176,14 @@ class OffloadScheduler:
             # then feeds the combiner its rows in one go.
             rows = [(row, pos_of[c.index]) for row, c in served]
 
-            def land(out=out, rows=rows):
+            def land(out=out, rows=rows, put=put):
                 try:
+                    if put is not None:
+                        # pop: the pool must not keep the group in HBM
+                        put.pop().block_until_ready()
+                        _trace.event_complete(
+                            "stage.put", put[0], time.monotonic() - put[0],
+                            offload=cmd.cmd_id, chunks=len(rows))
                     with _trace.span("stage.materialize", rows=len(rows)):
                         if isinstance(out, tuple):
                             bufs, ns = (np.asarray(v) for v in out)

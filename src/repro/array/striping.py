@@ -44,6 +44,7 @@ array is the degenerate single-device path.
 from __future__ import annotations
 
 import atexit
+import contextvars
 import queue
 import threading
 import time
@@ -92,6 +93,11 @@ class _GatherPool:
     shutdown. Bounded and shared — threads scale with concurrent gathers in
     progress, never with in-flight transfers, so the ring model's claim
     stands.
+
+    While tracing, each job runs in a copy of its submitter's context (or
+    in ``context``, for a submitter that is only a completion callback), so
+    its ``gather.exec`` span and the spans inside it inherit the submitting
+    offload's tags and name its span as their parent.
     """
 
     def __init__(self, max_workers: int = 4):
@@ -101,11 +107,14 @@ class _GatherPool:
         self._max = max_workers
         self._closed = False
 
-    def submit(self, fn: Callable[[], None]) -> None:
+    def submit(self, fn: Callable[[], None],
+               context: Optional[contextvars.Context] = None) -> None:
+        if context is None and _trace.enabled():
+            context = contextvars.copy_context()
         with self._lock:
             if not self._closed:
                 _registry().counter("gather.jobs").inc()
-                self._q.put((fn, time.monotonic()))
+                self._q.put((fn, time.monotonic(), context))
                 if len(self._threads) < self._max:
                     t = threading.Thread(
                         target=self._work, daemon=True,
@@ -118,6 +127,11 @@ class _GatherPool:
         # in result() with no timeout would hang forever
         fn()
 
+    @staticmethod
+    def _exec(fn: Callable[[], None]) -> None:
+        with _trace.span("gather.exec"):
+            fn()
+
     def _work(self) -> None:
         # queue-wait vs execute split is THE scaling-cliff discriminator for
         # this pool: growing wait with flat exec means the 4 workers (or the
@@ -127,12 +141,14 @@ class _GatherPool:
             item = self._q.get()
             if item is None:
                 return
-            fn, t_submit = item
+            fn, t_submit, context = item
             t0 = time.monotonic()
             reg.histogram("gather.queue_wait_seconds").observe(t0 - t_submit)
             try:
-                with _trace.span("gather.exec"):
-                    fn()
+                if context is None:
+                    self._exec(fn)
+                else:
+                    context.run(self._exec, fn)
             except Exception:
                 pass  # gather closures settle their barrier slot themselves
             reg.histogram("gather.exec_seconds").observe(

@@ -128,52 +128,42 @@ def execute_extent(
                 result.read_seconds = reader.read_seconds
             return result
         return interpret_program(program, read_page, n_pages, page_elems)
+    if tier not in (CsdTier.JIT, CsdTier.KERNEL):
+        raise ValueError(f"unknown tier {tier!r}")
     if tier == CsdTier.JIT:
-        jp, compile_seconds, hit = cache.get_or_build(
-            ("jit", program, n_pages, page_elems),
-            lambda: jit_program(program, n_pages, page_elems))
-        # steps 2,3: device DMA of the zone extent into device DRAM — a typed
-        # view of the backing buffer, not a host-side copy
-        t_r = time.perf_counter()
-        with _trace.span("tier.read", tier=tier, zone=zone_id,
-                         nblocks=n_blocks):
-            pages = device.read_extent(zone_id, block_off, n_blocks,
-                                       dtype).reshape(n_pages, page_elems)
-        read_seconds = time.perf_counter() - t_r
-        t0 = time.perf_counter()
-        with _trace.span("tier.compute", tier=tier, pages=n_pages):
-            value = jp(pages)
-            value = tuple(np.asarray(v) for v in value) \
-                if isinstance(value, tuple) else np.asarray(value)
-        exec_seconds = time.perf_counter() - t0
-        nbytes = (sum(v.nbytes for v in value) if isinstance(value, tuple)
-                  else value.nbytes)
-        return OffloadResult(value, nbytes, n_pages,
-                             insns_bound, exec_seconds, compile_seconds,
-                             read_seconds=read_seconds,
-                             cache_hits=int(hit), cache_misses=int(not hit))
-    if tier == CsdTier.KERNEL:
+        build = lambda: jit_program(program, n_pages, page_elems)
+    else:
         # Pallas tier (TPU target; interpret-mode on CPU); resolve_tier above
-        # already routed non-kernelizable programs to the JIT branch
+        # already routed non-kernelizable programs to the JIT tier
         from repro.kernels.zone_filter import ops as zf_ops
-        jp, compile_seconds, hit = cache.get_or_build(
-            ("kernel", program, n_pages, page_elems),
-            lambda: zf_ops.kernel_program(program, n_pages, page_elems))
-        t_r = time.perf_counter()
-        with _trace.span("tier.read", tier=tier, zone=zone_id,
-                         nblocks=n_blocks):
-            pages = device.read_extent(zone_id, block_off, n_blocks,
-                                       dtype).reshape(n_pages, page_elems)
-        read_seconds = time.perf_counter() - t_r
-        t0 = time.perf_counter()
-        with _trace.span("tier.compute", tier=tier, pages=n_pages):
-            value = np.asarray(jp(pages))
-        exec_seconds = time.perf_counter() - t0
-        return OffloadResult(value, value.nbytes, n_pages,
-                             insns_bound, exec_seconds, compile_seconds,
-                             read_seconds=read_seconds,
-                             cache_hits=int(hit), cache_misses=int(not hit))
-    raise ValueError(f"unknown tier {tier!r}")
+        build = lambda: zf_ops.kernel_program(program, n_pages, page_elems)
+    jp, compile_seconds, hit = cache.get_or_build(
+        (tier, program, n_pages, page_elems), build)
+    # steps 2,3: device DMA of the zone extent into device DRAM — a typed
+    # view of the backing buffer, not a host-side copy
+    t_r = time.perf_counter()
+    with _trace.span("tier.read", tier=tier, zone=zone_id, nblocks=n_blocks):
+        pages = device.read_extent(zone_id, block_off, n_blocks,
+                                   dtype).reshape(n_pages, page_elems)
+    read_seconds = time.perf_counter() - t_r
+    t0 = time.perf_counter()
+    if _trace.enabled():
+        # only while tracing: the call would put the pages itself, and
+        # waiting for the put here gives up its overlap with the dispatch
+        with _trace.span("tier.put", tier=tier, nbytes=pages.nbytes):
+            pages = jp.put(pages)
+            pages.block_until_ready()
+    with _trace.span("tier.run", tier=tier, pages=n_pages):
+        value = jp(pages)
+        value = tuple(np.asarray(v) for v in value) \
+            if isinstance(value, tuple) else np.asarray(value)
+    exec_seconds = time.perf_counter() - t0
+    nbytes = (sum(v.nbytes for v in value) if isinstance(value, tuple)
+              else value.nbytes)
+    return OffloadResult(value, nbytes, n_pages,
+                         insns_bound, exec_seconds, compile_seconds,
+                         read_seconds=read_seconds,
+                         cache_hits=int(hit), cache_misses=int(not hit))
 
 
 @dataclass

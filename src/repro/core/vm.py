@@ -305,6 +305,22 @@ class JittedProgram:
         with offload_x64():
             return self.fn(pages)
 
+    @staticmethod
+    def put(pages) -> jax.Array:
+        """Start the host-to-HBM put of ``pages`` that a call would make
+        itself, and return the device array without waiting for it."""
+        with offload_x64():
+            return jax.device_put(pages)
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, which ``jax.jit`` gives the executable: the
+    profiler's ``XLA Modules`` line then reads ``jit_<name>``."""
+    def inner(*args):
+        return fn(*args)
+    inner.__name__ = inner.__qualname__ = name
+    return inner
+
 
 def _stream_mask_jnp(program: Program, x: jnp.ndarray):
     mask = jnp.ones(x.shape, dtype=bool)
@@ -421,7 +437,7 @@ def jit_program(
     page at a time (bounded working set — the VMEM/CSD-DRAM constraint) and
     carries only the reduction accumulator."""
     dtype = np.dtype(program.input_dtype)
-    run = _build_program_runner(program)
+    run = named(_build_program_runner(program), "zcsd_jit_scan")
     spec = jax.ShapeDtypeStruct((n_pages, page_elems), dtype)
     t0 = time.perf_counter()
     # int64 accumulators need 64-bit mode at *trace* time; scope it to the
@@ -447,10 +463,11 @@ def jit_program_batched(
     ``([n_chunks, cap], [n_chunks])`` for SELECT) that the combiner then
     re-reduces in logical stripe order."""
     dtype = np.dtype(program.input_dtype)
-    run = _build_program_runner(program)
+    run = named(jax.vmap(_build_program_runner(program)),
+                "zcsd_jit_scan_batched")
     spec = jax.ShapeDtypeStruct((n_chunks, n_pages, page_elems), dtype)
     t0 = time.perf_counter()
     with offload_x64():
-        compiled = jax.jit(jax.vmap(run)).lower(spec).compile()
+        compiled = jax.jit(run).lower(spec).compile()
     compile_seconds = time.perf_counter() - t0
     return JittedProgram(compiled, compile_seconds, n_pages, page_elems, program)

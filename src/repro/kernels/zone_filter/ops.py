@@ -124,14 +124,16 @@ def run_program_kernel_batched(program: Program, pages: np.ndarray):
     return fn(jnp.asarray(pages))
 
 
-def _aot_compile(program: Program, kernel, shape: tuple[int, ...]):
+def _aot_compile(program: Program, kernel, shape: tuple[int, ...],
+                 name: str):
     """AOT lower+compile of ``kernel`` for ``program`` at ``shape``, returned
     as a :class:`~repro.core.vm.JittedProgram` (so the kernel tier reports
     the paper's 'JIT time' and caches exactly like the XLA JIT tier). Traced
     under the offload 64-bit scope like the XLA JIT tier so int64/float64
     zone dtypes keep their verified semantics."""
-    from repro.core.vm import JittedProgram  # local: keep import DAG one-way
-    run = _program_kernel(program, kernel)
+    # local: keep the import DAG one-way
+    from repro.core.vm import JittedProgram, named
+    run = named(_program_kernel(program, kernel), name)
     spec = jax.ShapeDtypeStruct(shape, np.dtype(program.input_dtype))
     t0 = time.perf_counter()
     with offload_x64():
@@ -143,7 +145,7 @@ def _aot_compile(program: Program, kernel, shape: tuple[int, ...]):
 def kernel_program(program: Program, n_pages: int, page_elems: int):
     """Compile a verified Program to a shaped Pallas executable."""
     return _aot_compile(program, filtered_reduce_pallas,
-                        (n_pages, page_elems))
+                        (n_pages, page_elems), "zcsd_kernel_scan")
 
 
 def kernel_program_batched(program: Program, n_chunks: int, n_pages: int,
@@ -152,4 +154,5 @@ def kernel_program_batched(program: Program, n_chunks: int, n_pages: int,
     ``[n_chunks, n_pages, page_elems]`` geometry (the scheduler's striped
     fan-out shape)."""
     return _aot_compile(program, filtered_reduce_pallas_batched,
-                        (n_chunks, n_pages, page_elems))
+                        (n_chunks, n_pages, page_elems),
+                        "zcsd_kernel_scan_batched")

@@ -22,6 +22,15 @@ Design constraints from the hot path:
     span's tags, so a ``stage.read_wait`` span inside ``offload.execute``
     inherits tenant/device tags it never set; contextvars also follow the
     code into coroutine-style callbacks better than thread-locals would.
+    Every event gets an ``id`` and its enclosing span's id as ``parent``,
+    so a job handed to another thread in a copy of the submitter's context
+    (the gather pool does this while tracing) still names what caused it.
+  * **one clock with the device** — while tracing, each span also opens a
+    ``jax.profiler.TraceAnnotation`` of its name, with its tags, id and
+    parent as arguments, on the same thread. Under a ``jax.profiler``
+    trace the program's spans then lie on the host plane of the same
+    ``.xplane.pb`` as the device's ops, on its clock. The disabled path
+    never builds one and never imports jax.
 
 Export is Chrome ``trace_event`` JSON (``{"traceEvents": [...]}``) with
 complete ("ph": "X") events: load it in Perfetto / chrome://tracing. Host
@@ -30,6 +39,7 @@ pid 2 (one row per ``track=`` name, e.g. ``dev0/zone3``).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -62,10 +72,33 @@ _enabled = False
 _rings_lock = threading.Lock()
 _rings: list["_Ring"] = []
 _local = threading.local()
+# Bumped by clear(): a thread whose ring is of an older generation starts a
+# new, registered one on its next append.
+_generation = 0
 
-# (name, tags) of the innermost live span — children inherit tags from it.
-_span_ctx: ContextVar[Optional[tuple[str, dict]]] = ContextVar(
+# (name, tags, id) of the innermost live span — children inherit its tags
+# and name its id as their parent.
+_span_ctx: ContextVar[Optional[tuple[str, dict, int]]] = ContextVar(
     "repro_trace_span", default=None)
+
+# Event ids, unique in the process; next() on a count is atomic under the GIL.
+_ids = itertools.count(1)
+
+# jax.profiler.TraceAnnotation, imported by the first enabled span.
+_annotation = None
+
+
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def _parent_id() -> Optional[int]:
+    parent = _span_ctx.get()
+    return None if parent is None else parent[2]
 
 
 def set_enabled(on: bool) -> None:
@@ -95,11 +128,12 @@ class _Ring:
     slot being overwritten is the worst case, and drain is a debugging/export
     operation, not a correctness path)."""
 
-    __slots__ = ("tid", "tname", "buf", "head", "dropped")
+    __slots__ = ("tid", "tname", "gen", "buf", "head", "dropped")
 
-    def __init__(self, tid: int, tname: str):
+    def __init__(self, tid: int, tname: str, gen: int):
         self.tid = tid
         self.tname = tname
+        self.gen = gen
         self.buf: list = [None] * RING_CAPACITY
         self.head = 0      # next write index (monotonic, wraps via modulo)
         self.dropped = 0   # events overwritten after the ring first filled
@@ -130,42 +164,54 @@ class _Ring:
 
 def _ring() -> _Ring:
     r = getattr(_local, "ring", None)
-    if r is None:
+    if r is None or r.gen != _generation:
         t = threading.current_thread()
-        r = _Ring(t.ident or 0, t.name)
-        _local.ring = r
         with _rings_lock:
+            r = _Ring(t.ident or 0, t.name, _generation)
             _rings.append(r)
+        _local.ring = r
     return r
 
 
-# Event tuples: ("X", name, ts, dur, tid_or_track, tags) for complete events
-# (tid_or_track is None → host thread row; a string → device virtual track),
-# ("I", name, ts, tags) for instants.
+# Event tuples: ("X", name, ts, dur, tid_or_track, tags, id, parent) for
+# complete events (tid_or_track is None → host thread row; a string → device
+# virtual track), ("I", name, ts, tags, id, parent) for instants.
 
 
 class _Span:
-    """A live span: records (ts, dur) around its body and pushes itself as
-    the contextvar parent so children inherit its tags."""
+    """A live span: records (ts, dur) around its body, pushes itself as the
+    contextvar parent so children inherit its tags, and holds a profiler
+    annotation of the same name open over the same body."""
 
-    __slots__ = ("name", "tags", "_t0", "_token")
+    __slots__ = ("name", "tags", "id", "parent", "_t0", "_token", "_ann")
 
-    def __init__(self, name: str, tags: dict):
+    def __init__(self, name: str, tags: dict, parent: Optional[int]):
         self.name = name
         self.tags = tags
+        self.id = next(_ids)
+        self.parent = parent
         self._t0 = 0.0
         self._token = None
+        self._ann = None
 
     def __enter__(self):
-        self._token = _span_ctx.set((self.name, self.tags))
+        self._token = _span_ctx.set((self.name, self.tags, self.id))
+        args = dict(self.tags, id=self.id)
+        if self.parent is not None:
+            args["parent"] = self.parent
+        self._ann = _profiler_annotation()(self.name, **args)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         dur = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        self._ann = None
         if self._token is not None:
             _span_ctx.reset(self._token)
-        _ring().append(("X", self.name, self._t0, dur, None, self.tags))
+        _ring().append(("X", self.name, self._t0, dur, None, self.tags,
+                        self.id, self.parent))
         return False
 
 
@@ -191,18 +237,21 @@ def span(name: str, **tags):
     if not _enabled:
         return _NOOP
     parent = _span_ctx.get()
-    if parent is not None and parent[1]:
+    if parent is None:
+        return _Span(name, tags, None)
+    if parent[1]:
         merged = dict(parent[1])
         merged.update(tags)
         tags = merged
-    return _Span(name, tags)
+    return _Span(name, tags, parent[2])
 
 
 def instant(name: str, **tags) -> None:
     """Zero-duration marker at now."""
     if not _enabled:
         return
-    _ring().append(("I", name, time.monotonic(), tags))
+    _ring().append(("I", name, time.monotonic(), tags, next(_ids),
+                    _parent_id()))
 
 
 def event_complete(name: str, ts: float, dur: float,
@@ -211,10 +260,15 @@ def event_complete(name: str, ts: float, dur: float,
     time enters the trace. The device model knows each transfer's claimed
     ``(start, service)`` window on the monotonic clock before it elapses;
     it calls this at submit time with ``track="dev0/zone3"`` and the event
-    lands on that device row rather than the submitting thread's row."""
+    lands on that device row rather than the submitting thread's row.
+
+    Its parent is the enclosing span, but it takes none of that span's tags
+    and, being post-hoc, it never reaches the profiler: it lives in the ring
+    and the Chrome export only."""
     if not _enabled:
         return
-    _ring().append(("X", name, ts, dur, track, tags))
+    _ring().append(("X", name, ts, dur, track, tags, next(_ids),
+                    _parent_id()))
 
 
 def dropped() -> int:
@@ -224,33 +278,36 @@ def dropped() -> int:
 
 def drain() -> list[dict]:
     """Snapshot all recorded events as dicts (wall seconds), oldest-first
-    per thread. Does not clear — export after a run, then :func:`clear`."""
+    per thread. Does not clear — export after a run, then :func:`clear`.
+    ``id`` is the event's own id, ``parent`` its enclosing span's id (None
+    at the top)."""
     with _rings_lock:
         rings = list(_rings)
     out = []
     for r in rings:
         for ev in r.events():
             if ev[0] == "X":
-                _, name, ts, dur, track, tags = ev
+                _, name, ts, dur, track, tags, eid, parent = ev
                 out.append({"type": "span", "name": name, "ts": ts,
                             "dur": dur, "track": track,
-                            "tid": r.tid, "thread": r.tname, "tags": tags})
+                            "tid": r.tid, "thread": r.tname, "tags": tags,
+                            "id": eid, "parent": parent})
             else:
-                _, name, ts, tags = ev
+                _, name, ts, tags, eid, parent = ev
                 out.append({"type": "instant", "name": name, "ts": ts,
-                            "tid": r.tid, "thread": r.tname, "tags": tags})
+                            "tid": r.tid, "thread": r.tname, "tags": tags,
+                            "id": eid, "parent": parent})
     out.sort(key=lambda e: e["ts"])
     return out
 
 
 def clear() -> None:
-    """Drop all recorded events and rings (fresh trace)."""
+    """Drop all recorded events and rings (fresh trace). Every thread,
+    long-lived pool workers included, starts a new ring on its next event."""
+    global _generation
     with _rings_lock:
         _rings.clear()
-    # Threads re-register on next append; stale thread-local rings are
-    # detached from _rings so their future events are invisible — replace
-    # the current thread's ring eagerly since it is the common writer.
-    _local.ring = None
+        _generation += 1
 
 
 _HOST_PID = 1
@@ -273,6 +330,9 @@ def to_chrome_events(events: Optional[list[dict]] = None) -> list[dict]:
     for e in events:
         ts_us = (e["ts"] - t0) * 1e6
         args = dict(e["tags"]) if e["tags"] else {}
+        args["id"] = e["id"]
+        if e["parent"] is not None:
+            args["parent"] = e["parent"]
         if e["type"] == "span" or e.get("track"):
             track = e.get("track")
             if track is not None:
