@@ -83,6 +83,13 @@ from repro.zns.device import ZNSError, block_aligned_dtype
 
 __all__ = ["OffloadScheduler", "ArrayOffloadStats", "ArrayOffloadError"]
 
+# Most bytes a batch group's staging buffer (and so one host-to-HBM put)
+# may hold: a large extent streams through many groups of this size, the
+# put of one overlapping the kernel of the one before, instead of a few
+# puts the size of the extent. On a TPU v5e a 4308 MiB scan ran fastest
+# at 128 MiB of budgets from 64 MiB to 1 GiB, all within 7% of it.
+_STAGE_GROUP_BYTES = 128 << 20
+
 
 class ArrayOffloadError(Exception):
     """A member device failed mid-offload (e.g. an OFFLINE zone). The message
@@ -189,7 +196,8 @@ class _StageGroup:
 
     chunks: list
     runs: list
-    pages: object = None           # staging buffer (None => zero-copy)
+    pages: object = None           # staging buffer (None: zero-copy, or
+                                   # handed to its dispatch)
     zero_copy: bool = False
     pending: int = 0               # runs not yet landed
     stage_s: float = 0.0           # memcpy time spent landing (gather pool)
@@ -941,12 +949,14 @@ class OffloadScheduler:
         transfer in flight before any compute runs.
 
         Full-size chunks (jit/kernel tiers, more than one) form the batch
-        groups: consecutive logical chunks, bucketed to a power-of-two batch
-        width, each group's member shares coalesced into maximal contiguous
-        runs — ONE ring read per run (raid0/xor coalesce whole groups;
-        raid1's round-robin replica assignment is member-locally
-        discontiguous and degrades to per-chunk runs, all still in flight up
-        front). Tail chunks and xor reconstructions submit alongside. A
+        groups: consecutive logical chunks, ``prefetch_depth`` groups of a
+        power-of-two batch width unless that width's staging buffer would
+        pass ``_STAGE_GROUP_BYTES`` (then as many groups of the widest power
+        of two that fits as the extent needs), each group's member shares
+        coalesced into maximal contiguous runs — ONE ring read per run
+        (raid0/xor coalesce whole groups; raid1's round-robin replica
+        assignment is member-locally discontiguous and degrades to
+        per-chunk runs, all still in flight up front). Tail chunks and xor reconstructions submit alongside. A
         member that fails AT SUBMISSION parks its chunks on the fallback
         list for the degraded re-serve (raid0 raises — the PR 2 clean-error
         contract)."""
@@ -965,16 +975,27 @@ class OffloadScheduler:
         staged = _StagedReads()
         if full:
             m = len(full)
-            # Split into pipeline groups, then bucket the group size to a
-            # power of two and zero-pad the tail group, so compiles stay
-            # O(#programs x log(total chunks)) instead of one per distinct
-            # extent size; pad-row outputs are discarded at dispatch. Floor
-            # of 2: a batch-of-1 variant would duplicate the plain
-            # single-chunk executable at the cost of an extra XLA compile.
-            n_groups = max(min(self.prefetch_depth, m), 1)
-            staged.m_b = max(1 << (-(-m // n_groups) - 1).bit_length(), 2)
+            # Split into prefetch_depth pipeline groups, then bucket the
+            # group size to a power of two and pad the tail group, so
+            # compiles stay O(#programs x log(total chunks)) instead of one
+            # per distinct extent size; pad-row outputs are discarded at
+            # dispatch. Then cap the width at the widest power of two whose
+            # staging buffer fits _STAGE_GROUP_BYTES, so a large extent
+            # streams through bounded puts and pads its tail group by less
+            # than one such buffer. Floor of 2: a batch-of-1 variant would
+            # duplicate the plain single-chunk executable at the cost of an
+            # extra XLA compile.
             page_elems, chunk_pages = extent_geometry(
                 array.block_bytes, dtype, stripe, self.pages_per_read)
+            n_groups = max(min(self.prefetch_depth, m), 1)
+            m_b = max(1 << (-(-m // n_groups) - 1).bit_length(), 2)
+            row_bytes = chunk_pages * page_elems * dtype.itemsize
+            cap = 1 << max(
+                (_STAGE_GROUP_BYTES // row_bytes).bit_length() - 1, 1)
+            if m_b > cap:
+                m_b = cap
+                _registry().counter("sched.stage.groups_capped").inc()
+            staged.m_b = m_b
             for i in range(0, m, staged.m_b):
                 grp_chunks = full[i:i + staged.m_b]
                 runs = []
@@ -1117,6 +1138,11 @@ class OffloadScheduler:
             agg.compile_s += compile_s
             agg.hits += int(hit)
             agg.misses += int(not hit)
+        # A group holds one of these slots from its put until its partial
+        # reaches the combiner: HBM then holds at most prefetch_depth group
+        # inputs, and at most that many pool threads wait in land() while
+        # later groups' staging copies need the rest.
+        slots = threading.BoundedSemaphore(max(self.prefetch_depth, 1))
         for grp in staged.groups:
             # read_wait = wall time the dispatcher BLOCKED on this group's
             # ring completions and their staging (near zero when earlier
@@ -1143,17 +1169,20 @@ class OffloadScheduler:
             dt = time.perf_counter() - t_w
             agg.read_wait_s += dt
             reg.histogram("sched.stage.read_wait_seconds").observe(dt)
+            # the dispatch below is the buffer's last use here: once the
+            # group has landed nothing of this offload keeps it alive
+            pages, grp.pages = grp.pages, None
             if not served:
                 continue
             if grp.zero_copy:
                 pages = np.asarray(raw0).reshape(m_b, chunk_pages, page_elems)
-            else:
-                pages = grp.pages
             agg.stage_s += grp.stage_s
             reg.histogram("sched.stage.staging_seconds").observe(grp.stage_s)
             put = None
+            slots.acquire()
             t_d = time.perf_counter()
-            with _trace.span("stage.dispatch", chunks=len(served)):
+            with _trace.span("stage.dispatch", chunks=len(served), rows=m_b,
+                             bytes=pages.nbytes):
                 if _trace.enabled():
                     # the put the call would start itself, started here so
                     # land() can record when it finished: the dispatcher
@@ -1163,6 +1192,7 @@ class OffloadScheduler:
                     out = jp(put[1])
                 else:
                     out = jp(pages)
+            del pages
             dt = time.perf_counter() - t_d
             agg.compute_s += dt
             agg.dispatches += 1
@@ -1173,7 +1203,10 @@ class OffloadScheduler:
             # the lazy jax result blocks until XLA finishes, and paying that
             # here would serialize group k's compute ahead of group k+1's
             # read wait and dispatch — the pool thread eats the wait instead,
-            # then feeds the combiner its rows in one go.
+            # then feeds the combiner its rows in one go. It runs ahead of
+            # queued staging copies, so the put is timed, and its slot
+            # freed, once the device is done and a pool thread is free,
+            # not once every queued copy is.
             rows = [(row, pos_of[c.index]) for row, c in served]
 
             def land(out=out, rows=rows, put=put):
@@ -1195,8 +1228,10 @@ class OffloadScheduler:
                     combiner.feed(vals)
                 except BaseException as e:
                     combiner.fail(e)
+                finally:
+                    slots.release()
 
-            _gather_executor().submit(land)
+            _gather_executor().submit(land, ahead=True)
         if staged.groups:
             agg.insns += program.n_insns * agg.batched * (
                 stripe // self.pages_per_read)

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import atexit
 import contextvars
+import itertools
 import queue
 import threading
 import time
@@ -98,23 +99,33 @@ class _GatherPool:
     in ``context``, for a submitter that is only a completion callback), so
     its ``gather.exec`` span and the spans inside it inherit the submitting
     offload's tags and name its span as their parent.
+
+    Jobs run in submission order, except that an ``ahead`` job runs before
+    every queued job that is not: a short job whose result the caller is
+    waiting for (a staged group landing its partial) must not queue behind
+    a backlog of staging memcpys.
     """
 
     def __init__(self, max_workers: int = 4):
-        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
+        # (rank, seq, job...): rank 0 ahead, 1 in order, 2 the stop marker;
+        # seq keeps submission order within a rank
+        self._q: "queue.PriorityQueue" = queue.PriorityQueue()
+        self._seq = itertools.count()
         self._lock = threading.Lock()
         self._threads: list[threading.Thread] = []
         self._max = max_workers
         self._closed = False
 
     def submit(self, fn: Callable[[], None],
-               context: Optional[contextvars.Context] = None) -> None:
+               context: Optional[contextvars.Context] = None, *,
+               ahead: bool = False) -> None:
         if context is None and _trace.enabled():
             context = contextvars.copy_context()
         with self._lock:
             if not self._closed:
                 _registry().counter("gather.jobs").inc()
-                self._q.put((fn, time.monotonic(), context))
+                self._q.put((0 if ahead else 1, next(self._seq), fn,
+                             time.monotonic(), context))
                 if len(self._threads) < self._max:
                     t = threading.Thread(
                         target=self._work, daemon=True,
@@ -138,10 +149,9 @@ class _GatherPool:
         # queue hand-off) are the serialization point, not the memcpys
         reg = _registry()
         while True:
-            item = self._q.get()
-            if item is None:
+            _rank, _seq, fn, t_submit, context = self._q.get()
+            if fn is None:
                 return
-            fn, t_submit, context = item
             t0 = time.monotonic()
             reg.histogram("gather.queue_wait_seconds").observe(t0 - t_submit)
             try:
@@ -161,7 +171,7 @@ class _GatherPool:
             self._closed = True
             threads = list(self._threads)
         for _ in threads:
-            self._q.put(None)
+            self._q.put((2, next(self._seq), None, 0.0, None))
         for t in threads:
             t.join(timeout=timeout)
 

@@ -3,12 +3,16 @@ arbitration/backpressure, scheduler result-equivalence vs the single-device
 NvmCsd oracle for every OpCode terminal, degraded-read reconstruction
 bit-identity under raid1/xor, and the fault paths: mid-fan-out member death,
 leaked-future regression, torn-append fencing, locked zone transitions."""
+import gc
+import math
 import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
 
+from repro.array import scheduler as scheduler_mod
 from repro.array import (
     ArrayOffloadError,
     Completion,
@@ -22,6 +26,7 @@ from repro.array import (
     WeightedRoundRobinArbiter,
 )
 from repro.core import CsdTier, NvmCsd, VerifyError
+from repro.core.vm import run_oracle
 from repro.core.programs import (
     Instruction,
     OpCode,
@@ -33,6 +38,8 @@ from repro.core.programs import (
     histogram,
     select_records,
 )
+from repro.telemetry import trace
+from repro.telemetry.metrics import registry
 from repro.zns import (
     OutOfBoundsError,
     ZonedDevice,
@@ -704,3 +711,177 @@ def test_batched_dispatch_bit_identical_across_tiers_and_modes(mode, n, tier):
                     if arr.devices[0].zones[z].write_pointer \
                     else ZoneState.EMPTY
     assert all(s.movement_saved_bytes > 0 for s in sched.history)
+
+
+# ------------------------------------------- stage groups capped by bytes
+
+ROW_BYTES = STRIPE * BLOCK          # one int32 chunk's pages in a group buffer
+CAPPED_PROGRAMS = TERMINAL_PROGRAMS[:6]   # COUNT, SUM, MIN, MAX, HIST, SELECT
+
+
+def _assert_same_answer(want, got):
+    if isinstance(want, tuple):
+        assert np.array_equal(np.asarray(want[0]), np.asarray(got[0]))
+        assert int(want[1]) == int(got[1])
+    else:
+        assert np.asarray(want).dtype == np.asarray(got).dtype
+        assert np.array_equal(np.asarray(want), np.asarray(got))
+
+
+def _spy_stage_buffers(monkeypatch) -> list:
+    """(weak reference, nbytes) of every staging buffer the scheduler
+    allocates from here on."""
+    seen = []
+    real = OffloadScheduler._stage_on_land
+
+    def spy(grp, run, chunk_pages, page_elems):
+        if not any(ref() is grp.pages for ref, _ in seen):
+            seen.append((weakref.ref(grp.pages), grp.pages.nbytes))
+        real(grp, run, chunk_pages, page_elems)
+
+    monkeypatch.setattr(OffloadScheduler, "_stage_on_land",
+                        staticmethod(spy))
+    return seen
+
+
+def _traced_offload(sched, program, **kw):
+    """Run one offload traced; returns its answer, stats, ``stage.dispatch``
+    spans and the registry's delta over it."""
+    trace.clear()
+    before = registry().snapshot()
+    with trace.tracing(True):
+        got, stats = sched.run_and_fetch(program, 0, **kw)
+    delta = registry().delta(before)
+    dispatches = [e for e in trace.drain() if e["name"] == "stage.dispatch"]
+    trace.clear()
+    return got, stats, dispatches, delta
+
+
+@pytest.mark.parametrize("mode,n,dead", [("raid0", 4, None), ("xor", 4, 0)],
+                         ids=["raid0", "xor-dead-member"])
+@pytest.mark.parametrize("program", CAPPED_PROGRAMS,
+                         ids=[p.name for p in CAPPED_PROGRAMS])
+def test_stage_groups_capped_by_bytes(monkeypatch, program, mode, n, dead):
+    """An extent whose prefetch-depth groups would pass the group budget
+    streams through budget-sized groups with a ragged tail: same answers
+    as the oracle, one dispatch a group, no buffer over the budget, and the
+    clamp counted once."""
+    budget = 5 * ROW_BYTES                     # the widest power of two: 4
+    monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES", budget)
+    data = int32_blocks(4 * 19 + 2, seed=31)   # 19 full chunks and a tail
+    arr = make_array(n, redundancy=mode, zone_kib=1024)
+    arr.zone_append(0, data)
+    if dead is not None:
+        arr.set_offline(0, device=dead)
+    buffers = _spy_stage_buffers(monkeypatch)
+    got, stats, dispatches, delta = _traced_offload(
+        OffloadScheduler(arr), program)
+    _assert_same_answer(run_oracle(program, data), got)
+    m = stats.batched_chunks
+    assert {e["tags"]["rows"] for e in dispatches} == {4}
+    assert stats.n_dispatches == len(dispatches) == -(-m // 4) >= 3
+    assert m % 4                               # the tail group is padded
+    assert buffers and all(nb <= budget for _, nb in buffers)
+    assert all(e["tags"]["bytes"] <= budget for e in dispatches)
+    assert delta["sched.stage.groups_capped"] == 1
+    assert (stats.degraded_reads > 0) == (dead is not None)
+
+
+@pytest.mark.parametrize("n_blocks,depth", [(40, 2), (64, 2), (78, 1),
+                                            (78, 3), (200, 2)])
+def test_stage_groups_under_budget_keep_prefetch_depth_rule(n_blocks, depth):
+    """Under the group budget the width is the prefetch-depth rule's:
+    ``prefetch_depth`` groups, bucketed up to a power of two, at least 2."""
+    data = int32_blocks(n_blocks, seed=32)
+    arr = make_array(4, zone_kib=1024)
+    arr.zone_append(0, data)
+    program = filter_sum("int32", "lt", 100)
+    got, stats, dispatches, delta = _traced_offload(
+        OffloadScheduler(arr, prefetch_depth=depth), program)
+    _assert_same_answer(run_oracle(program, data), got)
+    m = n_blocks // STRIPE
+    m_b = max(2, 1 << math.ceil(math.log2(-(-m // min(depth, m)))))
+    assert m_b * ROW_BYTES <= scheduler_mod._STAGE_GROUP_BYTES
+    assert stats.batched_chunks == m
+    assert {e["tags"]["rows"] for e in dispatches} == {m_b}
+    assert stats.n_dispatches == len(dispatches) == -(-m // m_b)
+    assert delta.get("sched.stage.groups_capped", 0) == 0
+
+
+@pytest.mark.parametrize("capped", [False, True], ids=["depth", "capped"])
+def test_stage_buffer_released_once_its_group_landed(monkeypatch, capped):
+    """By the combine rendezvous every group has landed its partial, and
+    nothing of the offload still holds a group's staging buffer."""
+    if capped:
+        monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES",
+                            4 * ROW_BYTES)
+    arr = make_array(4, zone_kib=1024)
+    arr.zone_append(0, int32_blocks(4 * 19, seed=33))
+    sched = OffloadScheduler(arr)
+    program = filter_count("int32", "gt", 0)
+    sched.nvm_cmd_bpf_run(program, 0)          # compile outside the check
+    buffers = _spy_stage_buffers(monkeypatch)
+    alive_at_rendezvous = []
+    real_result = scheduler_mod._StagedCombiner.result
+
+    def result(self):
+        self._done.wait()
+        deadline = time.monotonic() + 5.0
+        while (any(ref() is not None for ref, _ in buffers)
+               and time.monotonic() < deadline):
+            gc.collect()
+            time.sleep(0.01)
+        alive_at_rendezvous.append(
+            sum(ref() is not None for ref, _ in buffers))
+        return real_result(self)
+
+    monkeypatch.setattr(scheduler_mod._StagedCombiner, "result", result)
+    stats = sched.nvm_cmd_bpf_run(program, 0)
+    assert stats.n_dispatches == (5 if capped else 2)
+    assert len(buffers) == stats.n_dispatches
+    assert alive_at_rendezvous == [0]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_outstanding_groups_bounded_by_prefetch_depth(monkeypatch, depth):
+    """A group is dispatched only once all but ``prefetch_depth - 1`` of
+    the groups before it have materialized their partials, so HBM holds
+    at most that many group inputs and the pool at most that many lands."""
+    monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES", 2 * ROW_BYTES)
+    data = int32_blocks(4 * 12, seed=34)
+    arr = make_array(4, zone_kib=1024)
+    arr.zone_append(0, data)
+    sched = OffloadScheduler(arr, prefetch_depth=depth)
+    program = filter_count("int32", "gt", 0)
+    sched.nvm_cmd_bpf_run(program, 0)          # compile outside the check
+    trace.clear()
+    with trace.tracing(True):
+        got, _ = sched.run_and_fetch(program, 0)
+    events = trace.drain()
+    trace.clear()
+    assert int(got) == int(run_oracle(program, data))
+    starts = [e["ts"] for e in events if e["name"] == "stage.dispatch"]
+    ends = [e["ts"] + e["dur"] for e in events
+            if e["name"] == "stage.materialize"]
+    assert len(starts) == len(ends) == 6
+    for i, t in enumerate(sorted(starts)):
+        assert sum(end <= t for end in ends) >= i - depth + 1
+
+
+def test_gather_pool_runs_ahead_jobs_before_queued_ones():
+    from repro.array.striping import _GatherPool
+    pool = _GatherPool(max_workers=1)
+    gate, order = threading.Event(), []
+    try:
+        pool.submit(gate.wait)                 # holds the only worker
+        for i in range(3):
+            pool.submit(lambda i=i: order.append(i))
+        pool.submit(lambda: order.append("ahead"), ahead=True)
+        gate.set()
+        deadline = time.monotonic() + 5.0
+        while len(order) < 4 and time.monotonic() < deadline:
+            time.sleep(0.005)
+    finally:
+        gate.set()
+        pool.shutdown()
+    assert order == ["ahead", 0, 1, 2]
