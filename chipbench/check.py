@@ -1,35 +1,32 @@
 """The comparison that decides ``correct``.
 
-Every answer the window produced is held against the plain reference
-(:mod:`reference`) over the benchmark's own copy of the zone bytes, and every
-acknowledged append is read back through the storage's read path. The
-numbers compared, each against the limit the mix file gives it:
+Every answer the window produced is held against the plain reference of its
+program's kind (``programs/<kind>.py``: its ``answer``, importing nothing of
+the system) over the benchmark's own copy of the zone bytes, and every
+acknowledged append is read back through the storage's read path. The kind
+says which number each answer reads (its ``compare``) and how that number
+folds over the window (its ``NUMBERS``): the widest gap, or a count of wrong
+answers. Besides, whatever the kind:
 
-* ``count_gap``    widest |answer - reference| over COUNT answers;
-* ``sum_gap``      widest |answer - reference| over integer SUM answers;
-* ``fsum_rel_gap`` widest |answer - reference| / |reference| over float SUM
-  answers;
-* ``minmax_wrong`` MIN/MAX answers that differ from the reference;
-* ``select_wrong`` SELECT answers whose elements or count differ;
 * ``extent_wrong`` answered offloads whose reported bytes read differ from
   the extent the benchmark asked for;
 * ``append_bad``   acknowledged appended blocks that do not read back;
 * ``unanswered``   requests that failed, were refused or never completed.
 
-The extent of each answer is the benchmark's own: the blocks the job names,
-or the rest of the zone as the benchmark filled it. The reference reads
-exactly those bytes, whatever the program reports.
+Each number is held to the limit the mix file gives it. The extent of each
+answer is the benchmark's own: the blocks the job names, or the rest of the
+zone as the benchmark filled it. The reference reads exactly those bytes,
+whatever the program reports.
 """
 from __future__ import annotations
 
 import numpy as np
 
-import reference
+import named
 
 __all__ = ["readings", "judge"]
 
-NAMES = ("count_gap", "sum_gap", "fsum_rel_gap", "minmax_wrong",
-         "select_wrong", "extent_wrong", "append_bad", "unanswered")
+HARNESS = ("extent_wrong", "append_bad", "unanswered")
 
 
 def _extent(dep, job, n_blocks: int) -> np.ndarray:
@@ -45,6 +42,7 @@ def readings(records, dep, specs: dict, control: bool = False) -> dict:
     out = {"unanswered": 0}
     refs: dict = {}
     lows: dict = {}
+    order: dict = {}      # the kinds' numbers met, as an ordered set
     for rec in records:
         if not rec.ok:
             out["unanswered"] += 1
@@ -59,38 +57,29 @@ def readings(records, dep, specs: dict, control: bool = False) -> dict:
             out["append_bad"] = out.get("append_bad", 0) + int(bad.sum())
             continue
         spec = specs[job.program]
+        kind = named.program_kind(spec)
+        order.update(dict.fromkeys(kind.NUMBERS))
         if not control:
             out["extent_wrong"] = out.get("extent_wrong", 0) + int(
                 rec.reported_bytes != rec.n_blocks * dep.block_bytes)
         key = (job.program, job.zone, job.block_off, rec.n_blocks)
         data = _extent(dep, job, rec.n_blocks)
         if key not in refs:
-            refs[key] = reference.answer(spec, data)
+            refs[key] = kind.answer(spec, data)
         want = refs[key]
         if control:
             if key not in lows:
-                lows[key] = reference.control(spec, data)
+                lows[key] = kind.control(spec, data)
             got = lows[key]
         else:
             got = rec.value
-        red = spec["reduce"]
-        if red == "count":
-            _widest(out, "count_gap", abs(int(got) - int(want)))
-        elif red == "sum" and np.dtype(spec["dtype"]).kind in "iu":
-            _widest(out, "sum_gap", abs(int(got) - int(want)))
-        elif red == "sum":
-            gap = abs(float(got) - float(want)) / max(abs(float(want)),
-                                                      1e-300)
-            _widest(out, "fsum_rel_gap", gap)
-        elif red in ("min", "max"):
-            same = np.asarray(got, spec["dtype"])[()] == want
-            out["minmax_wrong"] = out.get("minmax_wrong", 0) + int(not same)
-        elif red == "select":
-            vals, n = got
-            same = int(n) == int(want[1]) and np.array_equal(
-                np.asarray(vals), want[0])
-            out["select_wrong"] = out.get("select_wrong", 0) + int(not same)
-    return out
+        name, v = kind.compare(spec, got, want)
+        if kind.NUMBERS[name] == "widest":
+            _widest(out, name, v)
+        else:
+            out[name] = out.get(name, 0) + int(v)
+    # the kinds' numbers in the order they declare them, then the harness's
+    return {n: out[n] for n in [*order, *HARNESS] if n in out}
 
 
 def _widest(out: dict, name: str, v) -> None:
@@ -101,9 +90,7 @@ def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:
     """``correct`` and each number beside its limit. A number the mix gives
     no limit is a fault of the mix, not a pass."""
     shown, ok = {}, True
-    for name in NAMES:
-        if name not in numbers:
-            continue
+    for name in numbers:
         if name not in limits:
             raise KeyError(f"the mix sets no limit for {name}")
         v, lim = numbers[name], limits[name]

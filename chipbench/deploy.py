@@ -3,7 +3,8 @@
 A configuration file (``configs/<name>.json``) names the entry point
 (``csd``: one ``ZonedDevice`` behind ``NvmCsd``; ``scheduler``: a
 ``StripedZoneArray`` behind ``OffloadScheduler``), the member geometry and the
-contents of each zone. Member latency emulation stays at the file's values,
+contents of each zone, whose ``dist`` names its generator,
+``zones/<dist>.py``. Member latency emulation stays at the file's values,
 which every configuration sets to zero. No tier or other tuning option is
 passed: each cell measures the path the system chooses itself.
 """
@@ -15,6 +16,8 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
+
+import named
 
 __all__ = ["Deployment", "build", "zone_data", "seed_words"]
 
@@ -29,26 +32,33 @@ def seed_words(seed: int, *salt: int) -> np.random.SeedSequence:
     return np.random.SeedSequence([seed % (1 << 64), *salt])
 
 
-def zone_data(spec: dict, seed: int, n_bytes: int) -> np.ndarray:
-    """The bytes of one zone as the configuration's ``zones`` entry says,
-    generated from ``seed`` on a few host threads into one buffer."""
+def zone_data(spec: dict, seed: int, n_bytes: int,
+              block_bytes: int) -> np.ndarray:
+    """The bytes of one zone of ``n_bytes`` capacity as the configuration's
+    ``zones`` entry says, generated from ``seed`` on a few host threads into
+    one buffer.
+
+    The generator, ``zones/<dist>.py``, has ``fill(spec, g, out, start)``:
+    it fills ``out``, the ``_CHUNK`` elements of the zone from element
+    ``start``, from its own seeded ``g``. It may have
+    ``elements(spec, capacity)``, the elements the zone holds where that is
+    fewer than its capacity; the zone is then zero-padded to whole blocks."""
+    gen = named.zone_kind(spec)
     dtype = np.dtype(spec["dtype"])
-    n = n_bytes // dtype.itemsize
-    out = np.empty(n, dtype)
+    cap = n_bytes // dtype.itemsize
+    n = int(gen.elements(spec, cap)) if hasattr(gen, "elements") else cap
+    if not 0 <= n <= cap:
+        raise ValueError(f"zone {spec['zone']}: {spec['dist']} asks for "
+                         f"{n} elements, the zone holds {cap}")
+    per_block = block_bytes // dtype.itemsize
+    out = np.empty(-(-n // per_block) * per_block, dtype)
+    out[n:] = 0
     kids = seed_words(seed, 1, int(spec["zone"])).spawn(-(-n // _CHUNK))
-    dist = spec["dist"]
 
     def fill(i: int) -> None:
         g = np.random.Generator(np.random.PCG64(kids[i]))
-        view = out[i * _CHUNK:(i + 1) * _CHUNK]
-        if dist == "uniform":
-            view[:] = g.integers(spec["low"], spec["high"], view.size,
-                                 dtype=dtype)
-        elif dist == "normal":
-            g.standard_normal(out=view, dtype=dtype)
-            view *= dtype.type(spec["scale"])
-        else:
-            raise ValueError(f"zone {spec['zone']}: unknown dist {dist!r}")
+        start = i * _CHUNK
+        gen.fill(spec, g, out[start:min(start + _CHUNK, n)], start)
 
     with ThreadPoolExecutor(min(12, os.cpu_count() or 1)) as ex:
         list(ex.map(fill, range(len(kids))))
@@ -121,10 +131,10 @@ def build(config: dict, seed: int) -> Deployment:
     dep.seconds = {"generate": 0.0, "append": 0.0}
     logical = zone_bytes * (members if config["entry"] == "scheduler" else 1)
     for spec in config["zones"]:
-        if spec["dist"] == "empty":
-            continue
         t0 = time.perf_counter()
-        data = zone_data(spec, seed, logical)
+        data = zone_data(spec, seed, logical, dep.block_bytes)
+        if not data.size:      # left empty: the mix's appends fill it
+            continue
         t1 = time.perf_counter()
         storage.zone_append(int(spec["zone"]), data)
         dep.seconds["generate"] += t1 - t0

@@ -2,7 +2,7 @@
 
 A mix (``traffic/<name>.json``) is data only::
 
-    {"programs": {"<name>": {<reference.py program spec>}, ...},
+    {"programs": {"<name>": {"kind": "<kind>", ...}, ...},  # programs/<kind>.py
      "tenants": [
         {"name": "default", "clients": 4,          # closed-loop clients
          "deck": [<job>, ...]                      # in order, round on round
@@ -29,6 +29,7 @@ from typing import Optional
 
 import numpy as np
 
+import named
 from deploy import seed_words
 
 __all__ = ["Job", "Record", "JobStream", "Driver", "program", "run_window",
@@ -171,20 +172,9 @@ class JobStream:
 
 
 def program(spec: dict):
-    """The system's ``Program`` for a mix's program spec."""
-    from repro.core.programs import Instruction, OpCode, Program
-    insns = []
-    if spec.get("filter"):
-        cmp, thr = spec["filter"]
-        insns.append(Instruction(OpCode["CMP_" + cmp.upper()], thr))
-    red = spec["reduce"]
-    if red == "select":
-        insns.append(Instruction(OpCode.SELECT))
-        return Program(spec["dtype"], tuple(insns),
-                       select_capacity=int(spec["capacity"]),
-                       name=spec["name"])
-    insns.append(Instruction(OpCode["RED_" + red.upper()]))
-    return Program(spec["dtype"], tuple(insns), name=spec["name"])
+    """The system's ``Program`` for a mix's program spec, as its kind
+    (``programs/<kind>.py``) builds it."""
+    return named.program_kind(spec).build(spec)
 
 
 class Driver:

@@ -7,7 +7,9 @@ From the root of a checkout. The cell, its configuration
 (``chipbench/configs/<config>.json``), its traffic mix
 (``chipbench/traffic/<traffic>.json``) and its metrics
 (``chipbench/metrics/<metric>.py``) are found by the names in
-``BENCHMARK.json``. The run builds the deployment and fills its zones from
+``BENCHMARK.json``; its zones' generators (``chipbench/zones/<dist>.py``)
+and its programs' kinds (``chipbench/programs/<kind>.py``) by the names in
+those files. The run builds the deployment and fills its zones from
 the seed, warms every shape the mix sends, measures for ``--seconds``, then
 holds every answer of the window to the numpy reference.
 
@@ -27,7 +29,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -40,6 +41,8 @@ from pathlib import Path  # noqa: E402
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(HERE))
+
+import named  # noqa: E402
 
 __all__ = ["Cell", "Context", "load_cell", "load_metric", "main", "run_cell"]
 
@@ -90,6 +93,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = _load_json(root / cfg["file"])
     mix = _load_json(HERE / "traffic" / f"{w['traffic']}.json")
+    # what the cell's zones hold and its programs mean, found before any run
+    for spec in config["zones"]:
+        named.zone_kind(spec)
+    for spec in mix["programs"].values():
+        named.program_kind(spec)
 
     def mine(m: dict) -> bool:
         return name in m.get("workloads", [name])
@@ -101,12 +109,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 def load_metric(name: str):
     """The ``read(ctx)`` of ``metrics/<name>.py``."""
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return named.load("metrics", name).read
 
 
 def _place_compile_cache(root: Path) -> None:

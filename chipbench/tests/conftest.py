@@ -12,3 +12,6 @@ for p in (str(BENCH), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+# a cell made of new files only, which the harness proper does not ship
+NEW_CELL = HERE / "data" / "new_cell"
+

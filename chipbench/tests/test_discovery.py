@@ -1,5 +1,7 @@
 """BENCHMARK.json keeps to its contract, and every configuration, mix and
-metric it names is found by that name."""
+metric it names is found by that name, as is every zone generator and
+program kind that a configuration or a mix names."""
+import ast
 import json
 import re
 import subprocess
@@ -7,14 +9,19 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, NEW_CELL, ROOT
 
+import named
 import run
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in SPEC["workloads"]]
+CONFIGS = sorted((BENCH / "configs").glob("*.json"))
+MIXES = sorted((BENCH / "traffic").glob("*.json"))
+KINDS = sorted((BENCH / "programs").glob("*.py")) + [
+    NEW_CELL / "programs" / "field.py"]
 
 
 def _one_line(s: str) -> bool:
@@ -116,3 +123,60 @@ def test_without_the_system_it_exits_nonzero(tmp_path):
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"})
     assert p.returncode != 0
     assert p.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=[p.stem for p in CONFIGS])
+def test_every_dist_resolves_to_a_file(path):
+    for spec in json.loads(path.read_text())["zones"]:
+        gen = named.zone_kind(spec)
+        assert callable(getattr(gen, "fill", None)) \
+            or gen.elements(spec, 1 << 20) == 0
+
+
+@pytest.mark.parametrize("path", MIXES, ids=[p.stem for p in MIXES])
+def test_every_kind_resolves_to_a_file(path):
+    for spec in json.loads(path.read_text())["programs"].values():
+        kind = named.program_kind(spec)
+        for part in ("build", "answer", "control", "compare"):
+            assert callable(getattr(kind, part))
+        assert set(kind.NUMBERS.values()) <= {"widest", "wrong"}
+
+
+@pytest.mark.parametrize("path", KINDS, ids=[p.stem for p in KINDS])
+def test_a_kinds_reference_imports_nothing_of_the_system(path):
+    """Only ``build`` may import ``repro``: the reference, the control and
+    the comparison are written from the semantics alone."""
+    tree = ast.parse(path.read_text())
+    found = []
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef) and node.name == "build":
+            continue
+        for sub in ast.walk(node):
+            if isinstance(sub, ast.Import):
+                found += [a.name for a in sub.names]
+            elif isinstance(sub, ast.ImportFrom):
+                found.append(sub.module or "")
+    assert not [m for m in found if m.split(".")[0] == "repro"], found
+
+
+def test_an_unknown_dist_fails_at_load_cell_naming_it(tmp_path):
+    cfg = json.loads((BENCH / "configs" / "zcsd-fig2.json").read_text())
+    cfg["zones"][0]["dist"] = "no-such-dist"
+    (tmp_path / "bad.json").write_text(json.dumps(cfg))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for c in bench["configs"]:
+        if c["name"] == "zcsd-fig2":
+            c["file"] = "bad.json"
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(LookupError, match="no-such-dist"):
+        run.load_cell("fig2.scan", root=tmp_path)
+
+
+def test_an_unknown_kind_fails_at_load_cell_naming_it(tmp_path, monkeypatch):
+    mix = json.loads((BENCH / "traffic" / "fig2-scan.json").read_text())
+    mix["programs"]["count_gt_half"]["kind"] = "no-such-kind"
+    (tmp_path / "traffic").mkdir()
+    (tmp_path / "traffic" / "fig2-scan.json").write_text(json.dumps(mix))
+    monkeypatch.setattr(run, "HERE", tmp_path)
+    with pytest.raises(LookupError, match="no-such-kind"):
+        run.load_cell("fig2.scan")

@@ -1,20 +1,21 @@
-"""The numpy reference agrees with the program's own oracle
-(``repro.core.run_oracle``) for every program the mixes send and every
-terminal it implements."""
+"""Every program kind's reference agrees with the program's own oracle
+(``repro.core.run_oracle``) where the oracle implements the program: the
+``filter`` kind for every program the mixes send and every terminal it
+implements, and the test-only ``field`` kind (FIELD, CMP, SUM)."""
 import json
 
 import numpy as np
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, NEW_CELL
 
 import loadgen
-import reference
+import named
 from repro.core import run_oracle
 
 MIXES = sorted((BENCH / "traffic").glob("*.json"))
-# every terminal the reference implements, so that a mix added as data alone
-# is held to a tested reference
+# every terminal the filter kind implements, so that a mix added as data
+# alone is held to a tested reference
 TERMINALS = {
     "min_gt_half": {"dtype": "int32", "filter": ["gt", 1073741823],
                     "reduce": "min"},
@@ -27,9 +28,11 @@ TERMINALS = {
 }
 PROGRAMS = sorted({(n, json.dumps(s, sort_keys=True))
                    for p in MIXES
-                   for n, s in json.loads(p.read_text())["programs"].items()}
+                   for n, s in json.loads(p.read_text())["programs"].items()
+                   if s.get("kind", "filter") == "filter"}
                   | {(n, json.dumps(s, sort_keys=True))
                      for n, s in TERMINALS.items()})
+FIELD = json.loads((NEW_CELL / "traffic" / "field-scan.json").read_text())
 
 
 def _data(dtype: str, n: int, seed: int) -> np.ndarray:
@@ -52,7 +55,7 @@ def test_reference_matches_run_oracle(name, spec, n):
     spec = dict(json.loads(spec), name=name)
     data = _data(spec["dtype"], n, n)
     want = run_oracle(loadgen.program(spec), data)
-    got = reference.answer(spec, data)
+    got = named.program_kind(spec).answer(spec, data.view(np.uint8))
     if spec["reduce"] == "sum" and spec["dtype"] == "float32":
         # two float64 summation orders over the same float32 elements
         assert abs(float(got) - float(want)) <= 1e-12 * abs(float(want))
@@ -64,5 +67,19 @@ def test_reference_matches_run_oracle(name, spec, n):
 def test_reference_on_an_empty_selection(name, spec):
     spec = dict(json.loads(spec), name=name)
     data = np.zeros(1024, spec["dtype"]) - 1
-    assert _same(reference.answer(spec, data),
+    assert _same(named.program_kind(spec).answer(spec, data.view(np.uint8)),
                  run_oracle(loadgen.program(spec), data))
+
+
+@pytest.mark.parametrize("name", sorted(FIELD["programs"]))
+@pytest.mark.parametrize("rows", [0, 128, 30000])
+def test_field_kind_matches_run_oracle(name, rows):
+    kind = named.load("programs", "field", NEW_CELL)
+    spec = dict(FIELD["programs"][name], name=name)
+    rng = np.random.default_rng(rows)
+    rec = rng.integers(-2**31, 2**31 - 1, (rows, spec["stride"]),
+                       dtype=np.int32)
+    rec[:, spec["index"]] = rng.integers(0, 10**9, rows, dtype=np.int32)
+    want = run_oracle(kind.build(spec), rec.reshape(-1))
+    assert kind.answer(spec, rec.view(np.uint8)) == want
+    assert kind.compare(spec, want, want) == ("field_gap", 0)
