@@ -30,6 +30,7 @@ import numpy as np
 from repro.core.cache import CompiledProgramCache
 from repro.core.prefetch import RingReader
 from repro.telemetry import trace as _trace
+from repro.telemetry.metrics import registry as _registry
 from repro.core.programs import OpCode, Program
 from repro.core.verifier import VerifierLimits, verify_program, verify_zone_access
 from repro.core.vm import (
@@ -147,13 +148,15 @@ def execute_extent(
                                    dtype).reshape(n_pages, page_elems)
     read_seconds = time.perf_counter() - t_r
     t0 = time.perf_counter()
+    record_tags = {}
     if _trace.enabled():
         # only while tracing: the call would put the pages itself, and
         # waiting for the put here gives up its overlap with the dispatch
         with _trace.span("tier.put", tier=tier, nbytes=pages.nbytes):
             pages = jp.put(pages)
             pages.block_until_ready()
-    with _trace.span("tier.run", tier=tier, pages=n_pages):
+        record_tags = _record_tags(program, pages.size)
+    with _trace.span("tier.run", tier=tier, pages=n_pages, **record_tags):
         value = jp(pages)
         value = tuple(np.asarray(v) for v in value) \
             if isinstance(value, tuple) else np.asarray(value)
@@ -164,6 +167,18 @@ def execute_extent(
                          insns_bound, exec_seconds, compile_seconds,
                          read_seconds=read_seconds,
                          cache_hits=int(hit), cache_misses=int(not hit))
+
+
+def _record_tags(program: Program, n_elems: int) -> dict:
+    """While tracing: a record program's ``tier.run`` tags (its ``stride``
+    and the ``columns`` it reads), and its records counted on the registry
+    as ``csd.records`` (zero-padded records of the extent's last block
+    included)."""
+    stride = program.stride
+    if stride is None:
+        return {}
+    _registry().counter("csd.records").inc(n_elems // stride)
+    return {"stride": stride, "columns": len(program.columns)}
 
 
 @dataclass
@@ -281,13 +296,13 @@ class NvmCsd:
 
         # steps 4: verify (static program + the zone extent it may touch)
         t0 = time.perf_counter()
-        insns_verified = verify_program(
-            program, page_elems=page_elems, n_pages=n_pages, limits=self.limits
-        )
-        verify_zone_access(
-            zone_write_pointer=zone.write_pointer, block_off=block_off,
-            n_blocks=n_blocks,
-        )
+        with _trace.span("csd.verify", zone=zone_id, program=program.name):
+            insns_verified = verify_program(
+                program, page_elems=page_elems, n_pages=n_pages,
+                limits=self.limits)
+            verify_zone_access(
+                zone_write_pointer=zone.write_pointer, block_off=block_off,
+                n_blocks=n_blocks)
         verify_seconds = time.perf_counter() - t0
 
         stats = OffloadStats(

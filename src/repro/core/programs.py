@@ -14,6 +14,11 @@ zone (interpreted at page granularity, exactly like the paper's prototype):
 
   * ``FIELD``      project one field out of fixed-stride records (optional,
                    must come first);
+  * record ops     ``LOAD`` replaces the stream with another column of the
+                   records FIELD defined, keeping the selection mask;
+                   ``MUL_FIELD`` multiplies the stream by a column (they need
+                   FIELD, so predicates over several columns of one record
+                   and a product of two columns stay linear);
   * ALU ops        elementwise arithmetic against an immediate;
   * ``CMP_*``      refine the selection mask (AND-composed);
   * one terminal   ``RED_COUNT | RED_SUM | RED_MIN | RED_MAX | RED_HIST |
@@ -45,6 +50,8 @@ __all__ = [
     "filter_select",
     "histogram",
     "field_reduce",
+    "tpch_q6",
+    "RECORD_OPS",
 ]
 
 SUPPORTED_DTYPES = ("int32", "int64", "uint32", "float32", "float64")
@@ -53,6 +60,8 @@ SUPPORTED_DTYPES = ("int32", "int64", "uint32", "float32", "float64")
 class OpCode(enum.Enum):
     # record projection
     FIELD = "field"          # imm = (stride, index): view stream as records
+    LOAD = "load"            # imm = index: stream := that column, mask kept
+    MUL_FIELD = "mul_field"  # imm = index: stream *= that column (wraps)
     # ALU (elementwise, against immediate)
     ADD = "add"
     SUB = "sub"
@@ -86,6 +95,7 @@ ALU_OPS = frozenset({
     OpCode.ADD, OpCode.SUB, OpCode.MUL, OpCode.AND, OpCode.OR, OpCode.XOR,
     OpCode.SHL, OpCode.SHR, OpCode.MOD, OpCode.ABS, OpCode.NEG,
 })
+RECORD_OPS = frozenset({OpCode.LOAD, OpCode.MUL_FIELD})
 INT_ONLY_OPS = frozenset({OpCode.AND, OpCode.OR, OpCode.XOR, OpCode.SHL, OpCode.SHR})
 CMP_OPS = frozenset({
     OpCode.CMP_GT, OpCode.CMP_GE, OpCode.CMP_LT, OpCode.CMP_LE,
@@ -127,6 +137,24 @@ class Program:
     @property
     def n_insns(self) -> int:
         return len(self.insns)
+
+    @property
+    def stride(self) -> Optional[int]:
+        """Record width in elements of a record program (FIELD first), else
+        None."""
+        first = self.insns[0]
+        return first.imm[0] if first.op == OpCode.FIELD else None
+
+    @property
+    def columns(self) -> frozenset[int]:
+        """The distinct record columns a record program reads (every column
+        for SELECT_REC, which returns whole records); empty otherwise."""
+        if self.stride is None:
+            return frozenset()
+        if self.terminal.op == OpCode.SELECT_REC:
+            return frozenset(range(self.stride))
+        return frozenset({self.insns[0].imm[1]} | {
+            i.imm for i in self.insns if i.op in RECORD_OPS})
 
     def result_dtype(self) -> np.dtype:
         t = self.terminal.op
@@ -201,3 +229,22 @@ def field_reduce(dtype: str, stride: int, index: int, kind: str = "sum",
         "min": OpCode.RED_MIN, "max": OpCode.RED_MAX,
     }[kind]))
     return Program(dtype, tuple(insns), name=f"field{index}_{kind}")
+
+
+def tpch_q6(stride: int, *, shipdate: int, discount: int, quantity: int,
+            extendedprice: int, date_lo: int, date_hi: int, disc_lo: int,
+            disc_hi: int, qty_lt: int, dtype: str = "int32") -> Program:
+    """TPC-H Q6 over ``stride``-wide records whose columns sit at the given
+    indices: SUM(extendedprice * discount) where ``date_lo <= shipdate <
+    date_hi``, ``disc_lo <= discount <= disc_hi`` and ``quantity < qty_lt``,
+    all in the integer units the records store."""
+    return Program(dtype, (
+        Instruction(OpCode.FIELD, (stride, shipdate)),
+        _cmp("ge", date_lo), _cmp("lt", date_hi),
+        Instruction(OpCode.LOAD, discount),
+        _cmp("ge", disc_lo), _cmp("le", disc_hi),
+        Instruction(OpCode.LOAD, quantity), _cmp("lt", qty_lt),
+        Instruction(OpCode.LOAD, extendedprice),
+        Instruction(OpCode.MUL_FIELD, discount),
+        Instruction(OpCode.RED_SUM),
+    ), name="tpch_q6")

@@ -16,7 +16,9 @@ program is admitted to the device, prove
      parameters sane;
   4. **structural safety** — exactly one terminal instruction, in final
      position; FIELD projection (if any) first, with a stride that divides
-     the page's element count so record boundaries never straddle pages.
+     the page's element count so record boundaries never straddle pages;
+     record ops (``LOAD``, ``MUL_FIELD``) only after a FIELD, on a column
+     inside its record.
 
 A rejected program never reaches any execution tier — the same contract the
 paper relies on for safe multi-tenant CSDs.
@@ -32,6 +34,7 @@ from repro.core.programs import (
     CMP_OPS,
     INT_ONLY_OPS,
     NO_IMM_OPS,
+    RECORD_OPS,
     SUPPORTED_DTYPES,
     TERMINAL_OPS,
     Instruction,
@@ -130,6 +133,16 @@ def verify_program(
                     f"{insn}: record stride {stride} does not divide page "
                     f"element count {page_elems} (records would straddle pages)"
                 )
+            continue
+        if op in RECORD_OPS:
+            # typed like MUL: the stream keeps its dtype and MUL_FIELD wraps
+            stride = program.stride
+            if stride is None:
+                raise VerifyError(f"{insn}: record op needs a FIELD first")
+            if not isinstance(insn.imm, int) or not 0 <= insn.imm < stride:
+                raise VerifyError(
+                    f"{insn}: column {insn.imm!r} outside records of stride "
+                    f"{stride}")
             continue
         if op in ALU_OPS or op in CMP_OPS:
             if op in INT_ONLY_OPS and not np.issubdtype(stream_dtype, np.integer):

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core.programs import (
     CMP_OPS,
+    RECORD_OPS,
     Instruction,
     OpCode,
     Program,
@@ -103,6 +104,15 @@ def _apply_cmp_np(x: np.ndarray, insn: Instruction) -> np.ndarray:
     }[insn.op]
 
 
+def _apply_record_np(x: np.ndarray, records: np.ndarray,
+                     insn: Instruction) -> np.ndarray:
+    col = records[:, insn.imm]
+    if insn.op == OpCode.LOAD:
+        return col
+    with np.errstate(over="ignore"):
+        return (x * col).astype(x.dtype)   # MUL_FIELD wraps like MUL
+
+
 def _hist_bin_np(x: np.ndarray, lo, hi, bins: int) -> tuple[np.ndarray, np.ndarray]:
     in_range = (x >= lo) & (x < hi)
     # use float64 bin math so int and float streams agree across tiers
@@ -141,6 +151,8 @@ def run_oracle(program: Program, data: np.ndarray) -> object:
             records = x.reshape(-1, stride)
             x = records[:, index]
             mask = np.ones(x.shape, dtype=bool)
+        elif insn.op in RECORD_OPS:
+            x = _apply_record_np(x, records, insn)
         elif insn.op in CMP_OPS:
             mask &= _apply_cmp_np(x, insn)
         else:
@@ -233,6 +245,12 @@ def interpret_program(
                 records = x.reshape(-1, stride)
                 x = records[:, index]
                 mask = np.ones(x.shape, dtype=bool)
+            elif insn.op in RECORD_OPS:
+                if records is None or insn.imm >= records.shape[1]:
+                    raise IndexError(
+                        f"{insn.op.value} access out of record bounds on "
+                        f"page {p}")
+                x = _apply_record_np(x, records, insn)
             elif insn.op in CMP_OPS:
                 mask &= _apply_cmp_np(x, insn)
             else:
@@ -328,8 +346,13 @@ def _stream_mask_jnp(program: Program, x: jnp.ndarray):
         op, imm = insn.op, insn.imm
         if op == OpCode.FIELD:
             stride, index = imm
-            x = x.reshape(-1, stride)[:, index]
+            records = x.reshape(-1, stride)
+            x = records[:, index]
             mask = jnp.ones(x.shape, dtype=bool)
+        elif op == OpCode.LOAD:
+            x = records[:, imm]
+        elif op == OpCode.MUL_FIELD:
+            x = x * records[:, imm]
         elif op in CMP_OPS:
             imm_t = jnp.asarray(imm, dtype=x.dtype)
             mask &= {
