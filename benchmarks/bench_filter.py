@@ -8,7 +8,7 @@ Scenarios:
                 (the paper's "SPDK without computational capabilities");
   2. interp   — ZCSD uBPF-analogue stack machine, one instruction at a time,
                 per-access bounds checks (paper scenario 2);
-  3. jit      — ZCSD with the program JIT-compiled (XLA), page-streamed
+  3. jit      — ZCSD with the program JIT-compiled (XLA), streamed in tiles of pages
                 (paper scenario 3; 'JIT time' reported separately);
   4. kernel   — Pallas zone-filter kernel (interpret mode on CPU) — the
                 additional hardware-backend tier the paper lists as ongoing

@@ -148,15 +148,17 @@ def execute_extent(
                                    dtype).reshape(n_pages, page_elems)
     read_seconds = time.perf_counter() - t_r
     t0 = time.perf_counter()
-    record_tags = {}
+    run_tags = {}
     if _trace.enabled():
         # only while tracing: the call would put the pages itself, and
         # waiting for the put here gives up its overlap with the dispatch
         with _trace.span("tier.put", tier=tier, nbytes=pages.nbytes):
             pages = jp.put(pages)
             pages.block_until_ready()
-        record_tags = _record_tags(program, pages.size)
-    with _trace.span("tier.run", tier=tier, pages=n_pages, **record_tags):
+        run_tags = _record_tags(program, pages.size)
+        if jp.steps is not None:
+            run_tags["steps"] = jp.steps      # the JIT scan's tile steps
+    with _trace.span("tier.run", tier=tier, pages=n_pages, **run_tags):
         value = jp(pages)
         value = tuple(np.asarray(v) for v in value) \
             if isinstance(value, tuple) else np.asarray(value)

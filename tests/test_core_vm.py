@@ -1,5 +1,6 @@
 """Offload VM semantics: all execution tiers agree with the numpy oracle,
 and the verifier rejects exactly the unsafe programs."""
+import jax
 import numpy as np
 import pytest
 
@@ -20,6 +21,8 @@ from repro.core import (
     run_oracle,
     verify_program,
 )
+from repro.core import vm
+from repro.core.vm import jit_program_batched, pages_per_step
 from repro.zns import ZonedDevice
 
 RNG = np.random.default_rng(42)
@@ -96,6 +99,88 @@ def test_empty_min_returns_identity():
     data = make_zone_data(n_pages=2, page_elems=128)
     oracle, interp, jit = run_all_tiers(program, data)
     assert oracle == interp == int(jit) == np.iinfo(np.int32).max
+
+
+# ------------------------------------------------------- JIT scan tiling
+
+STEP = 4                     # pages a tile, with the step constant made small
+TILE_PAGE_ELEMS = 128
+
+
+def assert_same_answer(got, want, program):
+    if isinstance(want, tuple):          # SELECT: (buffer, count of matches)
+        np.testing.assert_array_equal(np.asarray(got[0]), want[0])
+        assert int(got[1]) == int(want[1])
+    elif program.terminal.op == OpCode.RED_SUM and np.issubdtype(
+            np.asarray(want).dtype, np.floating):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def run_tiled(monkeypatch, program, data, batched):
+    """The JIT tier over ``data`` ([n_pages, page_elems], or a leading chunk
+    axis when ``batched``) with a tile of ``STEP`` pages; returns the
+    executable and one answer per extent."""
+    *lead, n_pages, page_elems = data.shape
+    batch = lead[0] if batched else 1
+    monkeypatch.setattr(vm, "_SCAN_STEP_BYTES",
+                        STEP * page_elems * data.itemsize * batch)
+    if batched:
+        jp = jit_program_batched(program, batch, n_pages, page_elems)
+        out = jp(data)
+        return jp, [jax.tree.map(lambda a, c=c: a[c], out)
+                    for c in range(batch)]
+    jp = jit_program(program, n_pages, page_elems)
+    return jp, [jp(data)]
+
+
+# capacity 300 of about 256 matches a tile: the buffer fills in the second
+TILED_PROGRAMS = PROGRAMS + [filter_select("int32", "gt", 0, capacity=300)]
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
+@pytest.mark.parametrize("n_pages", [1, STEP - 1, STEP, STEP + 1, 2 * STEP + 3])
+@pytest.mark.parametrize("program", TILED_PROGRAMS, ids=lambda p: p.name)
+def test_tiled_jit_scan_matches_oracle(monkeypatch, program, n_pages, batched):
+    """Full tiles, a tail step of its own shape, and an extent shorter than
+    one tile all give the page-by-page answer, per chunk when batched."""
+    dtype = np.dtype(program.input_dtype)
+    data = np.stack([make_zone_data(n_pages, TILE_PAGE_ELEMS, dtype, seed=s)
+                     for s in range(2 if batched else 1)])
+    jp, got = run_tiled(monkeypatch, program, data if batched else data[0],
+                        batched)
+    assert jp.pages_per_step == min(STEP, n_pages)
+    assert jp.steps == -(-n_pages // min(STEP, n_pages))
+    for chunk, answer in zip(data, got):
+        assert_same_answer(answer, run_oracle(program, chunk), program)
+
+
+PAGE_BYTES = 4096
+
+
+@pytest.mark.parametrize("n_pages", [275_712, 187_538],
+                         ids=["fig2-zone", "q6-lineitem"])
+def test_whole_zone_extents_step_in_tiles_with_a_tail(n_pages):
+    k = pages_per_step(n_pages, PAGE_BYTES)
+    assert 1 < k < n_pages
+    # the fewest pages whose bytes reach the step constant
+    assert k * PAGE_BYTES >= vm._SCAN_STEP_BYTES > (k - 1) * PAGE_BYTES
+    assert n_pages % k                    # the last step is a tail
+    steps = -(-n_pages // k)
+    assert steps == n_pages // k + 1
+    jp = vm.JittedProgram(None, 0.0, n_pages, 1024, PROGRAMS[0], k)
+    assert jp.steps == steps
+
+
+def test_array_scan_batch_keeps_one_page_a_step():
+    """2,048 chunks of 16 pages: a page over the batch is already 8 MiB."""
+    assert pages_per_step(16, PAGE_BYTES, batch=2048) == 1
+
+
+@pytest.mark.parametrize("n_pages", range(1, 17))
+def test_short_extent_is_one_step(n_pages):
+    assert pages_per_step(n_pages, PAGE_BYTES) == n_pages
 
 
 # ------------------------------------------------------------------ verifier

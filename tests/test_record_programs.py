@@ -5,6 +5,7 @@ give exactly what a plain-numpy Q6 gives, boundary rows included; the
 verifier refuses a record op without its FIELD or outside its record; and
 while tracing, Q6's offloads carry their record tags and count their
 records."""
+import jax
 import numpy as np
 import pytest
 
@@ -25,6 +26,7 @@ from repro.core import (
 )
 from repro.core.csd import resolve_tier
 from repro.core.programs import select_records, tpch_q6
+from repro.core import vm
 from repro.core.vm import jit_program_batched
 from repro.kernels.zone_filter.ops import kernelizable
 from repro.telemetry import trace
@@ -122,6 +124,43 @@ def test_tiers_agree_with_numpy_q6(seed, tier):
     assert np.asarray(got).dtype == np.int64
     assert int(got) == want
     assert want > 0
+
+
+STEP = 4                     # pages a JIT tile, with the step constant small
+PAGE_ELEMS = BLOCK // 4
+# about 59 of a tile's 128 records have a quantity under 24: a buffer of
+# 100 fills in the second tile
+TILED_PROGRAMS = [q6_program(),
+                  select_records("int32", STRIDE, QTY, "lt", 24, capacity=100)]
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
+@pytest.mark.parametrize("n_pages", [1, STEP - 1, STEP, STEP + 1, 2 * STEP + 3])
+@pytest.mark.parametrize("program", TILED_PROGRAMS, ids=lambda p: p.name)
+def test_tiled_jit_scan_of_records_matches_oracle(monkeypatch, program,
+                                                  n_pages, batched):
+    """Records read from tiles of ``STEP`` pages, a tail step and an extent
+    under one tile give the page-by-page answer, per chunk when batched."""
+    rows = n_pages * PAGE_ELEMS // STRIDE
+    extents = [_pages(lineitem_rows(s, rows)) for s in range(2 if batched
+                                                              else 1)]
+    monkeypatch.setattr(vm, "_SCAN_STEP_BYTES",
+                        STEP * BLOCK * len(extents))
+    if batched:
+        jp = jit_program_batched(program, 2, n_pages, PAGE_ELEMS)
+        out = jp(np.stack(extents))
+        got = [jax.tree.map(lambda a, c=c: a[c], out) for c in range(2)]
+    else:
+        jp = jit_program(program, n_pages, PAGE_ELEMS)
+        got = [jp(extents[0])]
+    assert jp.steps == -(-n_pages // min(STEP, n_pages))
+    for pages, answer in zip(extents, got):
+        want = run_oracle(program, pages)
+        if program.terminal.op == OpCode.SELECT_REC:
+            np.testing.assert_array_equal(np.asarray(answer[0]), want[0])
+            assert int(answer[1]) == int(want[1])
+        else:
+            assert int(answer) == int(want) == q6_numpy(pages, **PARAMS)
 
 
 def test_mul_field_wraps_like_mul():

@@ -16,7 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import filter_count, filter_sum
-from repro.core.programs import Instruction, OpCode, Program
+from repro.core import vm
+from repro.core.programs import Instruction, OpCode, Program, tpch_q6
 from repro.core.vm import _build_program_runner
 from repro.kernels.paged_attn.kernel import paged_attention_pallas
 from repro.kernels.zone_filter import ops as zf_ops
@@ -121,10 +122,34 @@ def test_paged_attention_compiles_at_granite_widths(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def _compile_in_place(run, n_pages, one_chip):
+    """Compile the single JIT runner over ``n_pages`` 4 KiB pages and check
+    that it reads the extent where it lies: the scratch it asks for stays
+    under one step's bytes, so no copy or padded copy of the extent."""
+    spec = jax.ShapeDtypeStruct((n_pages, PAGE), jnp.int32, sharding=one_chip)
+    with offload_x64():
+        compiled = jax.jit(run).lower(spec).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < vm._SCAN_STEP_BYTES
+
+
 @pytest.mark.parametrize("batched", [False, True], ids=["single", "vmapped"])
 def test_count_jit_runner_compiles(one_chip, batched):
-    """The XLA JIT tier's page scan with its int64 COUNT carry."""
+    """The XLA JIT tier's scan with its int64 COUNT carry: over fig2's
+    1077 MiB zone in tiles of pages and a tail step, and vmapped."""
     run = _build_program_runner(filter_count("int32", "gt", RAND_MAX // 2))
-    shape = (8, 16, PAGE) if batched else (65536, PAGE)
-    spec = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    _compile(jax.vmap(run) if batched else run, spec)
+    if batched:
+        spec = jax.ShapeDtypeStruct((8, 16, PAGE), jnp.int32,
+                                    sharding=one_chip)
+        _compile(jax.vmap(run), spec)
+    else:
+        assert 275_712 % vm.pages_per_step(275_712, 4 * PAGE)   # a tail
+        _compile_in_place(run, 275_712, one_chip)
+
+
+def test_q6_jit_runner_compiles_with_its_tail(one_chip):
+    """TPC-H Q6 over SF 1 ``lineitem`` (187,538 pages of 32 records): the
+    record tiles and the tail step lower for the chip."""
+    q6 = tpch_q6(32, shipdate=8, discount=6, quantity=4, extendedprice=5,
+                 date_lo=8766, date_hi=9131, disc_lo=5, disc_hi=7, qty_lt=24)
+    assert 187_538 % vm.pages_per_step(187_538, 4 * PAGE)
+    _compile_in_place(_build_program_runner(q6), 187_538, one_chip)

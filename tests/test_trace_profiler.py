@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.array import OffloadScheduler, StripedZoneArray
-from repro.core import vm
+from repro.core import NvmCsd, vm
 from repro.core.programs import filter_count
 from repro.kernels.zone_filter import ops as zf_ops
 from repro.telemetry import trace
@@ -241,6 +241,37 @@ def test_no_stage_staging_span_and_no_removed_offload_series(sched):
     assert not [k for k in registry().snapshot()
                 if k.startswith(("offload.dispatches", "offload.exec_",
                                  "offload.read_", "offload.overlap_"))]
+
+
+# ------------------------------------------------- the JIT scan's steps
+CSD_PAGES = 40               # with a 16-page tile: two full steps and a tail
+
+
+@pytest.fixture
+def csd_tiled(monkeypatch):
+    """``NvmCsd`` over one zone of ``CSD_PAGES`` 4 KiB blocks, its JIT scan
+    stepping 16 pages at a time."""
+    monkeypatch.setattr(vm, "_SCAN_STEP_BYTES", 16 * BLOCK)
+    dev = ZonedDevice(num_zones=1, zone_bytes=1 << 20, block_bytes=BLOCK)
+    dev.zone_append(0, np.arange(CSD_PAGES * BLOCK // 4, dtype=np.int32))
+    csd = NvmCsd(dev)
+    csd.run_and_fetch(PROGRAM, 0)          # compiled outside the trace
+    return csd
+
+
+def test_traced_tier_run_carries_the_scan_steps(csd_tiled):
+    with trace.tracing(True):
+        got, _ = csd_tiled.run_and_fetch(PROGRAM, 0)
+    (run,) = _by_name(trace.drain(), "tier.run")
+    k = vm.pages_per_step(CSD_PAGES, BLOCK)
+    assert k == 16
+    assert run["tags"]["steps"] == -(-CSD_PAGES // k) == 3
+    assert int(got) == CSD_PAGES * BLOCK // 4 - 1     # every element but 0
+
+
+def test_untraced_offload_records_no_steps(csd_tiled):
+    csd_tiled.run_and_fetch(PROGRAM, 0)
+    assert trace.drain() == []
 
 
 # ------------------------------------------------------ executable names
