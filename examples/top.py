@@ -14,8 +14,8 @@ terminal. Run as a script it drives a demo workload — a two-member raid1
 array serving two tenants, with a member zone killed partway through — so
 every pane has something to show::
 
-    PYTHONPATH=src python benchmarks/top.py              # live, ctrl-C to quit
-    PYTHONPATH=src python benchmarks/top.py --once       # single frame (CI)
+    PYTHONPATH=src python examples/top.py              # live, ctrl-C to quit
+    PYTHONPATH=src python examples/top.py --once       # single frame (CI)
 """
 from __future__ import annotations
 

@@ -224,10 +224,6 @@ class ArrayManager:
         with self._lock:
             return {m: dict(st) for m, st in self._member_status.items()}
 
-    def rebuild_active(self) -> bool:
-        with self._lock:
-            return any(t.is_alive() for t in self._threads.values())
-
     def wait(self, timeout: Optional[float] = None) -> bool:
         """Join every rebuild worker; True when all finished in time."""
         with self._lock:

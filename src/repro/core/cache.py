@@ -48,11 +48,6 @@ class CacheStats:
     size: int
     capacity: int
 
-    @property
-    def hit_rate(self) -> float:
-        total = self.hits + self.misses
-        return self.hits / total if total else 0.0
-
 
 @dataclass
 class _Build:

@@ -1,8 +1,7 @@
 """Deterministic fault injection: taxonomy, retry/timeout datapath, crash
 harness.
 
-Layer contracts pinned here (the live-traffic sweeps ride
-``benchmarks/bench_faults.py``):
+Contracts pinned here:
 
   * the injector is a pure function of (seed, key, op, seq) — identical
     schedules across instances and runs, per-class salt independence,
@@ -10,6 +9,8 @@ Layer contracts pinned here (the live-traffic sweeps ride
   * injected faults are **error completions through the ring** (never
     submit-time raises), absorbed by the bounded retry policy, escalated
     into the existing health/degraded pipeline only on budget exhaustion;
+  * an 8-member raid1 array serving offloads under 1% and 5% injected
+    read errors answers bit-identically with nobody ejected;
   * torn appends fence the logical zone at completion time; hung commands
     are rescued by per-op timeouts or diagnosed by ``result(timeout=)``;
   * two runs with one seed produce byte-identical offload results and the
@@ -484,6 +485,45 @@ class TestOffloadUnderFaults:
         engine.evaluate()
         assert not any(k for k in engine.active().get("retry_storm",
                                                       set()))
+
+    @pytest.mark.parametrize("rate", [0.01, 0.05])
+    def test_live_offloads_ride_through_injected_read_errors(self, rate):
+        """An 8-member raid1 array serving offloads under seeded transient
+        read errors: every answer and every zone's bytes equal the
+        fault-free truth, no retry budget exhausts, no member leaves the
+        HEALTHY/SUSPECT band and no zone goes offline."""
+        devices = [_dev(num_zones=2, zone_blocks=128) for _ in range(8)]
+        array = StripedZoneArray(devices, stripe_blocks=16,
+                                 redundancy="raid1")
+        rng = np.random.default_rng(0)
+        expected, baseline = [], []
+        for z in range(2):
+            data = rng.integers(0, RAND_MAX, array.zone_blocks * BLOCK // 8,
+                                dtype=np.int32)   # half of each logical zone
+            array.zone_append(z, data)
+            expected.append(int((data > RAND_MAX // 2).sum()))
+            baseline.append(array.read_zone(z).copy())
+        # the fills ran clean; only the offloads and read-backs see faults
+        inj = FaultInjector(2112, FaultSpec(read_error_rate=rate))
+        inj.attach_array(array, policy=RetryPolicy(max_attempts=6,
+                                                   backoff_base_s=50e-6))
+        program = filter_count("int32", "gt", RAND_MAX // 2)
+        with OffloadScheduler(array) as sched:
+            sched.register_tenant("t")
+            for _ in range(3):
+                for z in range(2):
+                    sched.nvm_cmd_bpf_run(program, z, tenant="t")
+                    assert int(sched.nvm_cmd_bpf_result()) == expected[z]
+        for z in range(2):
+            assert np.array_equal(array.read_zone(z), baseline[z])
+        stats = [d.stats for d in array.devices]
+        assert sum(s["read_errors"] + s["append_errors"] for s in stats) == 0
+        worst = max(m.sample() for m in ArrayHealthMonitor(array).members)
+        assert worst <= HealthStatus.SUSPECT, worst.name
+        assert all(array.zone(z).state.value != "offline" for z in range(2))
+        if rate >= 0.05:
+            assert sum(s["faults_injected"] for s in stats) > 0
+            assert sum(s["retries"] for s in stats) > 0
 
 
 # ------------------------------------------------------ determinism witness

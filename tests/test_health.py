@@ -1,10 +1,10 @@
 """Health telemetry: event log, SMART monitors, alert engine, instrumentation.
 
-The fault-path sweep lives in ``benchmarks/bench_health.py`` (it needs a
-live array under offload traffic); these tests pin the layer contracts —
-bounded event-log memory, exact counts under get-or-create races, the
-HEALTHY→SUSPECT→DEGRADED→OFFLINE state walk, edge-triggered alerting —
-on private registries/logs so nothing leaks between tests.
+The layer tests pin the contracts — bounded event-log memory, exact
+counts under get-or-create races, the HEALTHY→SUSPECT→DEGRADED→OFFLINE
+state walk, edge-triggered alerting — on private registries/logs so
+nothing leaks between tests. ``TestLiveArrayPipeline`` drives the whole
+chain on a live raid1 array under two tenants' offloads.
 """
 from __future__ import annotations
 
@@ -14,12 +14,15 @@ import threading
 import numpy as np
 import pytest
 
+from repro.array import OffloadScheduler, StripedZoneArray
+from repro.core import filter_count
 from repro.telemetry.alerts import (AlertEngine, ErrorRateRule,
                                     HealthPromotionRule, TenantLatencySLORule)
 from repro.telemetry.events import EventLog, Severity, event_log
-from repro.telemetry.health import DeviceHealthMonitor, HealthStatus
-from repro.telemetry.metrics import MetricsRegistry
-from repro.zns import ZonedDevice
+from repro.telemetry.health import (ArrayHealthMonitor, DeviceHealthMonitor,
+                                    HealthStatus)
+from repro.telemetry.metrics import MetricsRegistry, registry
+from repro.zns import ZNSError, ZonedDevice
 from repro.zns.device import ZoneStateError
 
 
@@ -355,3 +358,99 @@ class TestQueueEvents:
             sq.submit(cmd())
         rejects = log.snapshot(name="sq.reject", since_seq=seq0)
         assert rejects and rejects[0].tags["tenant"] == "t0"
+
+
+# --------------------------------------------- the live-array pipeline
+class TestLiveArrayPipeline:
+    def test_member_death_walks_counters_events_alerts_and_tenants(self):
+        """Zones of one raid1 member die under two tenants' offloads: the
+        member's counters move, it is sampled SUSPECT and then DEGRADED,
+        the alert fires into the log and the callback, the probe's error
+        growth fires the error-rate rule, degraded offloads stay
+        bit-identical and are charged to the tenant that ran them, and an
+        impossible p99 SLO pages for each tenant. Runs on the global
+        registry and event log, the shape the scheduler publishes to."""
+        rand_max = 2**31 - 1
+        zone_bytes = 256 * 4096
+        data = np.random.default_rng(0).integers(
+            0, rand_max, zone_bytes // 4, dtype=np.int32)
+        expected = int((data > rand_max // 2).sum())
+        program = filter_count("int32", "gt", rand_max // 2)
+        # 4 zones a member: killing member 1's zones one at a time walks
+        # it HEALTHY -> SUSPECT (1/4 offline) -> DEGRADED (3/4 >= 0.5)
+        devices = [ZonedDevice(num_zones=4, zone_bytes=zone_bytes,
+                               block_bytes=4096) for _ in range(2)]
+        array = StripedZoneArray(devices, stripe_blocks=64,
+                                 redundancy="raid1")
+        array.zone_append(0, data)
+
+        log = event_log()
+        seq0 = log.last_seq()
+        monitor = ArrayHealthMonitor(array)
+        monitor.register_on(registry())
+        promoted: list = []            # the spare-promotion callback's inbox
+        engine = AlertEngine(rules=[
+            HealthPromotionRule(monitor),
+            ErrorRateRule(pattern="health.*_errors", name="error_rate"),
+        ])
+        engine.on_alert(promoted.append)
+
+        with OffloadScheduler(array) as sched:
+            sched.register_tenant("alice")
+            sched.register_tenant("bob")
+            for tenant in ("alice", "bob"):
+                for _ in range(2):
+                    sched.nvm_cmd_bpf_run(program, 0, tenant=tenant)
+                    assert int(sched.nvm_cmd_bpf_result()) == expected
+            assert engine.evaluate() == []
+            assert monitor.worst() is HealthStatus.HEALTHY
+            ts = sched.tenant_stats()
+            for tenant in ("alice", "bob"):
+                t = ts[tenant]
+                assert t["ops"] >= 2 and t["bytes"] > 0, t
+                assert t["p99_s"] >= t["p50_s"] > 0.0, t
+
+            # one zone of member 1 dies mid-stream
+            before = devices[1].metrics.snapshot()
+            array.set_offline(0, device=1)
+            after = devices[1].metrics.snapshot()
+            assert after["zone_offline_transitions"] == \
+                before.get("zone_offline_transitions", 0) + 1
+
+            for _ in range(2):
+                stats = sched.nvm_cmd_bpf_run(program, 0, tenant="alice")
+                assert int(sched.nvm_cmd_bpf_result()) == expected
+            assert stats.degraded_reads > 0
+            assert stats.tenant == "alice"
+            tot = stats.tenant_totals
+            assert tot["degraded_reads"] > 0 and tot["ops"] > 0, tot
+            assert tot["bytes"] > 0 and tot["p99_s"] >= tot["p50_s"] > 0.0
+            assert sched.tenant_stats()["bob"]["degraded_reads"] == 0
+
+            # SUSPECT: sampled by the promotion rule, below its threshold
+            assert engine.evaluate() == []
+            assert monitor.statuses()[1] is HealthStatus.SUSPECT
+            assert [e for e in log.snapshot(name="health.status",
+                                            since_seq=seq0)
+                    if e.tags.get("to_status") == "SUSPECT"]
+
+            # a host probe of the dead zone moves the SMART error counter
+            with pytest.raises(ZNSError):
+                devices[1].read_blocks(0, 0, 1)
+            assert devices[1].stats["read_errors"] >= 1
+
+            # past the zone-fraction threshold: DEGRADED, alert, callback
+            array.set_offline(1, device=1)
+            array.set_offline(2, device=1)
+            fired = engine.evaluate()
+            assert any(a.rule == "member_degraded" for a in fired), fired
+            assert any(a.rule == "member_degraded" for a in promoted)
+            assert any(a.rule == "error_rate" for a in fired), fired
+            assert monitor.statuses()[1] >= HealthStatus.DEGRADED
+            for name in ("alert.member_degraded", "array.member_offline",
+                         "zone.offline", "array.degraded_read"):
+                assert log.snapshot(name=name, since_seq=seq0), name
+
+            engine.add_rule(TenantLatencySLORule(1e-9))
+            slo = [a for a in engine.evaluate() if a.rule == "tenant_p99_slo"]
+            assert {a.tags["tenant"] for a in slo} >= {"alice", "bob"}, slo

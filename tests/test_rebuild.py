@@ -4,9 +4,11 @@ Pins the ISSUE 8 contracts: member_shard address math against real device
 contents, per-zone cutover (rebuilt zones take appends while later zones
 still copy), full-lifecycle bit-identity across raid1/xor at 2/4/8 members
 (including a member killed mid-rebuild), idempotent alert-path promotion
-with incident resolution, xor double-fault degrading OFFLINE without
-corruption or hangs, scrub catching injected bit rot and feeding the
-health monitor, and checkpoint restore riding a mid-rebuild array.
+with incident resolution, unattended recovery with offloads served
+bit-identically while the rebuild is held between zones, xor
+double-fault degrading OFFLINE without corruption or hangs, scrub
+catching injected bit rot and feeding the health monitor, and
+checkpoint restore riding a mid-rebuild array.
 """
 import threading
 
@@ -274,6 +276,71 @@ class TestFullLifecycle:
             assert ts["scrub"]["ops"] > 0
         finally:
             sched.close()
+
+    def test_unattended_recovery_under_live_offloads(self):
+        """A raid1 member dies and nobody calls promote_spare: the alert
+        path promotes the spare, offloads issued while the rebuild is held
+        between zones (zone 0 cut over, zones 1-2 still copying) return
+        the healthy answers, and afterwards every zone is writable and
+        bit-identical, scrubs find nothing, and the copy and scrub traffic
+        were metered on their own tenants."""
+        arr = make_array(2, num_zones=3, zone_kib=128, redundancy="raid1")
+        for z in range(3):
+            arr.zone_append(z, int32_blocks(arr.zone_blocks // 2 + 2 * z,
+                                            seed=z))
+        before = [arr.read_zone(z).copy() for z in range(3)]
+        mon = ArrayHealthMonitor(arr)
+        engine = AlertEngine(rules=[HealthPromotionRule(mon)])
+        held, release = threading.Event(), threading.Event()
+
+        def hold_after_first_cutover(e):
+            # runs on the rebuild worker, outside the array lock
+            if e.name == "array.zone_rebuilt" and not held.is_set():
+                held.set()
+                release.wait(timeout=60)
+
+        prog = filter_sum("int32", "ge", 0)
+        with OffloadScheduler(arr) as sched:
+            sched.register_tenant("reader")
+            healthy = [sched.run_and_fetch(prog, z, tenant="reader")[0]
+                       for z in range(3)]
+            mgr = ArrayManager(arr, scheduler=sched, monitor=mon,
+                               spares=[make_device(3, 128)], rows_per_io=1)
+            mgr.attach(engine)
+            mon.sample()
+            assert engine.evaluate() == []
+            seq0 = event_log().last_seq()
+            unsub = event_log().subscribe(hold_after_first_cutover)
+            try:
+                kill_member(arr, 1)
+                fired = engine.evaluate()
+                assert any(a.rule == "member_degraded" for a in fired)
+                assert event_log().snapshot(name="spare.promoted",
+                                            since_seq=seq0)
+                assert held.wait(timeout=60), "no zone was rebuilt"
+                assert sorted(arr.rebuilding_zones()) == [1, 2]
+                assert mgr.status()[1]["state"] == "running"
+                during = [sched.run_and_fetch(prog, z, tenant="reader")[0]
+                          for z in range(3)]
+            finally:
+                release.set()
+                unsub()
+            assert during == healthy
+            assert mgr.wait(timeout=60)
+            assert mgr.status()[1]["state"] == "complete"
+            for z in range(3):
+                assert arr.zone(z).is_writable
+                assert np.array_equal(arr.read_zone(z), before[z])
+            assert mgr.scrub()["mismatches"] == 0
+            after = [sched.run_and_fetch(prog, z, tenant="reader")[0]
+                     for z in range(3)]
+            assert after == healthy
+            for z in range(3):
+                arr.zone_append(z, int32_blocks(1, seed=9))
+            assert mgr.scrub()["mismatches"] == 0
+            ts = sched.tenant_stats()
+            assert ts["rebuild"]["ops"] > 0 and ts["rebuild"]["bytes"] > 0
+            assert ts["scrub"]["ops"] > 0
 
     def test_promotion_is_idempotent(self):
         arr = make_array(2, num_zones=2, zone_kib=128, redundancy="raid1")

@@ -330,6 +330,47 @@ class TestInstrumentation:
         assert evs[0]["track"] == f"dev{dev.dev_ordinal}/z0"
         assert evs[0]["dur"] == pytest.approx(1e-6, rel=0.5)
 
+    def test_traced_array_offload_exports_host_and_device_rows(self, tmp_path):
+        """One traced offload over a two-member array exports a Chrome
+        trace with its host span on pid 1 and the members' virtual-time
+        reads on pid 2, every event well formed and none dropped."""
+        from repro.array import OffloadScheduler, StripedZoneArray
+        from repro.core import filter_count
+        from repro.zns import ZonedDevice
+        data = np.random.default_rng(0).integers(0, 2**31 - 1, (1 << 20) // 4,
+                                                 dtype=np.int32)
+        devices = [ZonedDevice(num_zones=1, zone_bytes=1 << 20,
+                               block_bytes=4096, read_us_per_block=1.0)
+                   for _ in range(2)]
+        program = filter_count("int32", "gt", 2**30)
+        with StripedZoneArray(devices, stripe_blocks=16) as array:
+            array.zone_append(0, data)
+            with OffloadScheduler(array) as sched:
+                sched.nvm_cmd_bpf_run(program, 0)    # compile untraced
+                trace.clear()
+                with trace.tracing(True):
+                    sched.nvm_cmd_bpf_run(program, 0)
+                assert int(sched.nvm_cmd_bpf_result()) == \
+                    int((data > 2**30).sum())
+        path = tmp_path / "trace.json"
+        trace.export_chrome(str(path))
+        doc = json.loads(path.read_text())
+        evs = doc["traceEvents"]
+        assert evs and doc["otherData"]["dropped_events"] == 0
+        for e in evs:
+            assert isinstance(e["name"], str) and e["name"]
+            assert e["ph"] in {"X", "M", "i"}
+            assert isinstance(e["pid"], int)
+            if e["ph"] != "M":
+                assert isinstance(e["tid"], int) and e["ts"] >= 0
+            if e["ph"] == "X":
+                assert e["dur"] >= 0
+        spans = {(e["pid"], e["name"]) for e in evs if e["ph"] == "X"}
+        assert (1, "offload.execute") in spans
+        assert (2, "dev.read") in spans
+        assert {m["args"]["name"] for m in evs if m["name"] == "process_name"} \
+            == {"host threads", "device virtual time"}
+
     def test_checkpoint_store_stats_migrated(self):
         from repro.train.checkpoint import ZonedCheckpointStore
         from repro.zns import ZonedDevice
