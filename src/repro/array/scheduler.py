@@ -17,8 +17,10 @@ The :class:`OffloadScheduler` scales that contract to a
      coalesce_member_runs`), tail-chunk reads, xor survivor reconstructions
      — so in-flight depth is bounded by the emulated devices, not a thread
      pool (:mod:`repro.zns.ring`). The COMPUTE stage is ONE dispatcher that
-     consumes staged groups in logical order and issues ONE array-wide
-     batched compiled call per group over the chunks of ALL members: a
+     consumes staged groups in logical order and hands each member's
+     share to HBM as the view its read returned when it is one long run
+     (raid0), else packed with the group's other short runs by one host
+     copy, in power-of-two pieces, one batched compiled call a piece: a
      vmapped XLA call on the JIT tier
      (:func:`repro.core.vm.jit_program_batched`) or a grid-batched Pallas
      call on the kernel tier
@@ -45,6 +47,7 @@ from __future__ import annotations
 import contextvars
 import threading
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -83,9 +86,9 @@ from repro.zns.device import ZNSError, block_aligned_dtype
 
 __all__ = ["OffloadScheduler", "ArrayOffloadStats", "ArrayOffloadError"]
 
-# Most bytes a batch group's staging buffer (and so one host-to-HBM put)
-# may hold: a large extent streams through many groups of this size, the
-# put of one overlapping the kernel of the one before, instead of a few
+# Most zone bytes a batch group (and so its pieces' host-to-HBM puts) may
+# hold: a large extent streams through many groups of this size, the
+# puts of one overlapping the kernels of the one before, instead of a few
 # puts the size of the extent. On a TPU v5e a 4308 MiB scan ran fastest
 # at 128 MiB of budgets from 64 MiB to 1 GiB, all within 7% of it.
 _STAGE_GROUP_BYTES = 128 << 20
@@ -111,14 +114,14 @@ class ArrayOffloadStats(OffloadStats):
     n_devices: int = 1
     n_chunks: int = 1
     batched_chunks: int = 0        # chunks executed via a batched compiled call
-    n_dispatches: int = 0          # array-wide batched compiled calls issued
+    n_dispatches: int = 0          # batched compiled calls (one a piece)
     # chunks served without their preferred member: raid1 mirror redirects
     # plus xor reconstructions (degraded offloads stay bit-identical; this
     # counter is how an operator notices the array is running degraded)
     degraded_reads: int = 0
     compute_seconds: float = 0.0   # time inside compiled/interp execution only
     read_wait_seconds: float = 0.0 # wall the compute stage BLOCKED on reads
-    stage_seconds: float = 0.0     # staging memcpys into the batch buffer
+    stage_seconds: float = 0.0     # packing short runs on the host
     combine_seconds: float = 0.0   # combiner folds (run on the gather pool)
     # max(read_seconds - read_wait_seconds, 0): member transfer time the
     # pipeline hid — under compute, and under other members' transfers
@@ -176,44 +179,57 @@ class _StageAgg:
 @dataclass
 class _MemberRun:
     """One coalesced member read of a batch group: ``items`` are
-    ``(row, chunk)`` pairs (row = slot in the group's batch buffer),
-    ascending and contiguous in member-local space — ONE ring transfer."""
+    ``(position, chunk)`` pairs (position within the group), ascending and
+    contiguous in member-local space — ONE ring transfer. ``view``: the run
+    goes to HBM as the view its read returned; else it is packed."""
 
     device: int
     items: list
     fut: object
+    view: bool = False
 
 
 @dataclass
 class _StageGroup:
-    """One batch group: the chunks that share one array-wide dispatch.
+    """One batch group: consecutive logical chunks, staged together and
+    held in HBM together.
 
-    Member runs land into the shared ``pages`` staging buffer from their
-    ring completions (on the gather pool) — ``staged`` flips once every
-    surviving run has scattered its rows. A group whose single run already
-    covers every batch row in member order skips the buffer entirely
-    (``zero_copy``) and dispatches the device view directly."""
+    A run that is its member's whole share of the group, two or more
+    chunks long, goes to HBM as the view its read returned. Every other run
+    (raid1's round-robin replicas, xor's runs cut by parity rows, one-chunk
+    shares) is gathered into ``packed`` by one host copy on the gather pool
+    once the last of them retires; ``staged`` flips when that copy is done
+    (at once when the group has none)."""
 
     chunks: list
     runs: list
-    pages: object = None           # staging buffer (None: zero-copy, or
-                                   # handed to its dispatch)
-    zero_copy: bool = False
-    pending: int = 0               # runs not yet landed
-    stage_s: float = 0.0           # memcpy time spent landing (gather pool)
+    packed: object = None          # [rows, pages, elems] of the packed runs
+                                   # (None: nothing packed, or handed to
+                                   # its dispatch)
+    packed_items: list = field(default_factory=list)
+    pending: int = 0               # packed runs not yet retired
+    stage_s: float = 0.0           # packing time (gather pool)
+    landing: int = 0               # dispatched pieces not yet landed
+    put_t0: float = 0.0            # first piece's put started (tracing)
+    put_end: float = 0.0           # latest piece's put found ready
     lock: threading.Lock = field(default_factory=threading.Lock)
     staged: threading.Event = field(default_factory=threading.Event)
+
+
+def _pieces(n: int) -> list[int]:
+    """Widths, widest first, of the power-of-two pieces ``n`` rows split
+    into (its set bits): compiles stay one a width, O(log rows)."""
+    return [1 << b for b in range(n.bit_length() - 1, -1, -1) if n >> b & 1]
 
 
 @dataclass
 class _StagedReads:
     """Everything the READ stage put in flight, for the compute stage to
-    consume: batch groups (one array-wide dispatch each), per-chunk tail
-    reads, xor-reconstruction reads, and chunks whose member failed at
-    submission time (re-served through the degraded path)."""
+    consume: batch groups, per-chunk tail reads, xor-reconstruction reads,
+    and chunks whose member failed at submission time (re-served through
+    the degraded path)."""
 
     groups: list = field(default_factory=list)
-    m_b: int = 0                   # padded batch width shared by all groups
     rest: list = field(default_factory=list)      # (chunk, member fut)
     recon: list = field(default_factory=list)     # (chunk, array fut)
     fallback: list = field(default_factory=list)  # chunks to re-serve
@@ -859,12 +875,12 @@ class OffloadScheduler:
            No thread parks per transfer; in-flight depth is bounded by the
            emulated devices.
         2. **compute stage** — ONE dispatcher consumes staged groups in
-           logical order and issues ONE array-wide batched compiled call per
-           group over the chunks of ALL members (total chunk count is a
-           property of the logical extent, so the dispatch shape — and the
-           host work — is constant across array widths). Tail, degraded and
-           fallback chunks ride the same staged bytes through the plain
-           per-chunk executables.
+           logical order and hands each member's share to HBM as it lies
+           when it is one long run, else packed with the group's other
+           short runs, one batched compiled call per power-of-two piece
+           (raid0 over N members: N runs a group, one call per set bit of
+           a run's length). Tail, degraded and fallback chunks ride the same staged
+           bytes through the plain per-chunk executables.
         3. **combine stage** — the program-aware combiner folds per-chunk
            partials in logical stripe order ON THE GATHER POOL as results
            land, off the straggler's critical path; the stage span covers
@@ -950,16 +966,17 @@ class OffloadScheduler:
 
         Full-size chunks (jit/kernel tiers, more than one) form the batch
         groups: consecutive logical chunks, ``prefetch_depth`` groups of a
-        power-of-two batch width unless that width's staging buffer would
-        pass ``_STAGE_GROUP_BYTES`` (then as many groups of the widest power
-        of two that fits as the extent needs), each group's member shares
+        power-of-two width unless that width's bytes would pass
+        ``_STAGE_GROUP_BYTES`` (then as many groups of the widest power of
+        two that fits as the extent needs), each group's member shares
         coalesced into maximal contiguous runs — ONE ring read per run
-        (raid0/xor coalesce whole groups; raid1's round-robin replica
-        assignment is member-locally discontiguous and degrades to
-        per-chunk runs, all still in flight up front). Tail chunks and xor reconstructions submit alongside. A
-        member that fails AT SUBMISSION parks its chunks on the fallback
-        list for the degraded re-serve (raid0 raises — the PR 2 clean-error
-        contract)."""
+        (raid0 coalesces a member's whole share; xor's parity rows cut it
+        into runs of N-1 chunks, and raid1's round-robin replica assignment
+        into one-chunk runs, all still in flight up front, and packed on
+        landing: :meth:`_pack_on_land`). Tail chunks and xor
+        reconstructions submit alongside. A member that fails AT SUBMISSION
+        parks its chunks on the fallback list for the degraded re-serve
+        (raid0 raises its clean error: :class:`ArrayOffloadError`)."""
         array = self.array
         stripe = array.stripe_blocks
         dtype = np.dtype(program.input_dtype)
@@ -975,16 +992,10 @@ class OffloadScheduler:
         staged = _StagedReads()
         if full:
             m = len(full)
-            # Split into prefetch_depth pipeline groups, then bucket the
-            # group size to a power of two and pad the tail group, so
-            # compiles stay O(#programs x log(total chunks)) instead of one
-            # per distinct extent size; pad-row outputs are discarded at
-            # dispatch. Then cap the width at the widest power of two whose
-            # staging buffer fits _STAGE_GROUP_BYTES, so a large extent
-            # streams through bounded puts and pads its tail group by less
-            # than one such buffer. Floor of 2: a batch-of-1 variant would
-            # duplicate the plain single-chunk executable at the cost of an
-            # extra XLA compile.
+            # Split into prefetch_depth pipeline groups of a power-of-two
+            # width, then cap the width at the widest power of two whose
+            # bytes fit _STAGE_GROUP_BYTES, so a large extent streams
+            # through bounded puts. Floor of 2: a group is never one chunk.
             page_elems, chunk_pages = extent_geometry(
                 array.block_bytes, dtype, stripe, self.pages_per_read)
             n_groups = max(min(self.prefetch_depth, m), 1)
@@ -995,12 +1006,12 @@ class OffloadScheduler:
             if m_b > cap:
                 m_b = cap
                 _registry().counter("sched.stage.groups_capped").inc()
-            staged.m_b = m_b
-            for i in range(0, m, staged.m_b):
-                grp_chunks = full[i:i + staged.m_b]
+            for i in range(0, m, m_b):
+                grp_chunks = full[i:i + m_b]
+                plan = coalesce_member_runs(grp_chunks, stripe)
+                shares = Counter(dev_idx for dev_idx, _ in plan)
                 runs = []
-                for dev_idx, items in coalesce_member_runs(grp_chunks,
-                                                           stripe):
+                for dev_idx, items in plan:
                     n_blocks = sum(c.n_blocks for _, c in items)
                     try:
                         fut = array.devices[dev_idx].submit_read(
@@ -1010,30 +1021,18 @@ class OffloadScheduler:
                         self._member_failed(dev_idx, zone_id, e)
                         staged.fallback.extend(c for _, c in items)
                         continue
-                    runs.append(_MemberRun(dev_idx, items, fut))
+                    # A member's whole share is one long run under raid0
+                    # and 1-member arrays: a view of it is one batch input.
+                    # Shorter runs would each be calls of their own, so
+                    # they are packed into one part a group.
+                    view = shares[dev_idx] == 1 and len(items) > 1
+                    runs.append(_MemberRun(dev_idx, items, fut, view))
                 grp = _StageGroup(grp_chunks, runs)
-                one = runs[0] if len(runs) == 1 else None
-                if (one is not None and len(one.items) == staged.m_b
-                        and all(row == j
-                                for j, (row, _) in enumerate(one.items))):
-                    # the one run covers every batch row in member order
-                    # (the 1-member case): dispatch the device view as-is
-                    grp.zero_copy = True
-                    grp.staged.set()
+                packed = [r for r in runs if not r.view]
+                if packed:
+                    self._pack_on_land(grp, packed, chunk_pages, page_elems)
                 else:
-                    # np.empty, not zeros: every served row is overwritten by
-                    # staging, and rows whose member read failed feed garbage
-                    # to batch outputs that are discarded — zero-filling
-                    # 2×stripe-width of pages here costs real dispatcher
-                    # milliseconds at 8 members
-                    grp.pages = np.empty(
-                        (staged.m_b, chunk_pages, page_elems), dtype)
-                    grp.pending = len(runs)
-                    if not runs:
-                        grp.staged.set()
-                    for run in runs:
-                        self._stage_on_land(grp, run, chunk_pages,
-                                            page_elems)
+                    grp.staged.set()
                 staged.groups.append(grp)
         for c in rest:
             try:
@@ -1057,56 +1056,70 @@ class OffloadScheduler:
         return staged
 
     @staticmethod
-    def _stage_on_land(grp: "_StageGroup", run: "_MemberRun",
-                       chunk_pages: int, page_elems: int) -> None:
-        """Scatter one member run into the group's staging buffer the moment
-        its ring completion retires — on the gather pool, never the reactor
-        thread — so staging memcpys hide under the remaining members'
-        transfers and the previous group's dispatch instead of serializing
-        on the dispatcher's critical path. The ``stage.copy`` span encloses
-        the copy that ``sched.stage.staging_seconds`` sums."""
-        def copy():
-            with _trace.span("stage.copy", device=run.device,
-                             rows=len(run.items)):
+    def _pack_on_land(grp: "_StageGroup", runs: list,
+                      chunk_pages: int, page_elems: int) -> None:
+        """Gather a group's packed runs into one host part, in the order of
+        ``runs``, by ONE vectorized copy on the gather pool once the last
+        of them has retired — never on the reactor thread — so the copy
+        hides under the previous group's dispatch. Runs whose read failed
+        are left out (the dispatcher re-serves their chunks). The
+        ``stage.copy`` span encloses the copy that
+        ``sched.stage.staging_seconds`` sums; viewed runs are never copied,
+        so only packing emits either."""
+        def pack():
+            ok = [r for r in runs if r.fut.error is None]
+            rows = sum(len(r.items) for r in ok)
+            with _trace.span("stage.copy", rows=rows):
                 t0 = time.perf_counter()
                 try:
-                    if run.fut.error is None:
-                        part = np.asarray(run.fut.value).reshape(
-                            len(run.items), chunk_pages, page_elems)
-                        for j, (row, _c) in enumerate(run.items):
-                            grp.pages[row] = part[j]
+                    if ok:
+                        grp.packed_items = [it for r in ok for it in r.items]
+                        grp.packed = np.concatenate(
+                            [np.asarray(r.fut.value).reshape(-1)
+                             for r in ok]).reshape(
+                                rows, chunk_pages, page_elems)
                 finally:
-                    with grp.lock:
-                        grp.stage_s += time.perf_counter() - t0
-                        grp.pending -= 1
-                        if grp.pending == 0:
-                            grp.staged.set()
+                    grp.stage_s = time.perf_counter() - t0
+                    grp.staged.set()
+
         # The completion callback runs on the reactor, outside the offload:
         # while tracing, hand the pool the offload's context from here.
         ctx = contextvars.copy_context() if _trace.enabled() else None
-        # Always hop to the gather pool: the callback fires inline on the
-        # DISPATCHER thread when a short emulated transfer retires before
-        # registration, and an inline memcpy there serializes all staging
-        # into the read-submission loop — the exact cliff this stage hides.
-        run.fut.add_done_callback(
-            lambda _f: _gather_executor().submit(copy, ctx))
+
+        def retired(_f) -> None:
+            with grp.lock:
+                grp.pending -= 1
+                last = grp.pending == 0
+            if last:
+                # Always hop to the gather pool: the callback fires inline on
+                # the DISPATCHER thread when a short emulated transfer
+                # retires before registration, and an inline copy there
+                # serializes packing into the read-submission loop.
+                _gather_executor().submit(pack, ctx)
+
+        grp.pending = len(runs)
+        for run in runs:
+            run.fut.add_done_callback(retired)
 
     # -------------------------------------------------------- compute stage
     def _compute_stage(self, cmd: OffloadCommand, staged: "_StagedReads",
                        pos_of: dict[int, int], agg: "_StageAgg",
                        combiner: "_StagedCombiner") -> None:
         """COMPUTE stage: one dispatcher thread drains the staged reads in
-        logical order and issues one array-wide batched compiled call per
-        group; every partial is handed to the combiner the moment it exists,
-        so combining overlaps the next group's read wait and dispatch.
+        logical order. A group's rows are laid out member-major: the viewed
+        runs in :func:`coalesce_member_runs` order, each as the view its
+        read returned, then the group's one packed part; each is cut into
+        power-of-two pieces, one batched compiled call a piece. Every
+        partial reaches the combiner by logical position the moment it
+        exists, so logical order needs no interleave, and combining
+        overlaps the next piece's put.
 
         A ``TransientIOError`` surfacing on one member's group read does NOT
-        poison the batch: the surviving runs still stage and dispatch
-        together (the dead member's rows stay unstaged and their outputs
-        are discarded), and the failed member's chunks re-serve individually
-        through the array's degraded read — raid1 mirror redirect / xor
-        reconstruction, the exact observable behavior of the pre-staged
-        per-worker fallback."""
+        poison the batch: the surviving runs still dispatch (the dead
+        member's run is left out of the group), and its chunks re-serve
+        individually through the array's degraded read — raid1 mirror
+        redirect / xor reconstruction, the exact observable behavior of the
+        pre-staged per-worker fallback."""
         program, zone_id, tier = cmd.program, cmd.zone_id, cmd.tier
         array = self.array
         reg = _registry()
@@ -1120,118 +1133,153 @@ class OffloadScheduler:
             combiner.feed({pos_of[c.index]: agg.vals.pop(c.index)})
 
         if staged.groups:
-            m_b = staged.m_b
             dtype = np.dtype(program.input_dtype)
             page_elems, chunk_pages = extent_geometry(
                 array.block_bytes, dtype, stripe, self.pages_per_read)
+
+        def executable(width: int):
+            """The batched executable of ``width`` chunks: one compile a
+            power-of-two width."""
             if tier == CsdTier.KERNEL:
                 from repro.kernels.zone_filter import ops as zf_ops
-                key = ("kernel_batched", program, m_b, chunk_pages,
+                key = ("kernel_batched", program, width, chunk_pages,
                        page_elems)
                 builder = lambda: zf_ops.kernel_program_batched(
-                    program, m_b, chunk_pages, page_elems)
+                    program, width, chunk_pages, page_elems)
             else:
-                key = ("jit_batched", program, m_b, chunk_pages, page_elems)
+                key = ("jit_batched", program, width, chunk_pages, page_elems)
                 builder = lambda: jit_program_batched(
-                    program, m_b, chunk_pages, page_elems)
+                    program, width, chunk_pages, page_elems)
             jp, compile_s, hit = self.cache.get_or_build(key, builder)
             agg.compile_s += compile_s
             agg.hits += int(hit)
             agg.misses += int(not hit)
-        # A group holds one of these slots from its put until its partial
-        # reaches the combiner: HBM then holds at most prefetch_depth group
-        # inputs, and at most that many pool threads wait in land() while
-        # later groups' staging copies need the rest.
+            return jp
+
+        # A group holds one of these slots from its first piece's put until
+        # its last piece's partial reaches the combiner: HBM then holds at
+        # most prefetch_depth groups' inputs, and at most that many groups'
+        # pieces wait in land() while later groups' packing needs the pool.
         slots = threading.BoundedSemaphore(max(self.prefetch_depth, 1))
-        for grp in staged.groups:
+        for g, grp in enumerate(staged.groups):
             # read_wait = wall time the dispatcher BLOCKED on this group's
-            # ring completions and their staging (near zero when earlier
+            # ring completions and its packing (near zero when earlier
             # groups' dispatch covered the transfers) — the number that
             # grows if the pipeline serializes on I/O
-            served = []
-            raw0 = None
+            parts = []         # (items, pages[rows, chunk_pages, elems], direct)
             t_w = time.perf_counter()
             with _trace.span("stage.read_wait", chunks=len(grp.chunks)):
                 for run in grp.runs:
                     try:
-                        raw0 = run.fut.result(self.io_timeout_s)
+                        view = run.fut.result(self.io_timeout_s)
                     except (ZNSError, TransientIOError) as e:
                         self._member_failed(run.device, zone_id, e)
                         staged.fallback.extend(c for _, c in run.items)
                         continue
                     agg.read_s += run.fut.service_seconds
-                    served.extend(run.items)
+                    if run.view:
+                        # the read's own view of the member's zone bytes
+                        parts.append((run.items, np.asarray(view).reshape(
+                            len(run.items), chunk_pages, page_elems), True))
                 if not grp.staged.wait(self.io_timeout_s):
                     raise TimeoutError(
                         f"offload staging stalled on zone {zone_id}: "
-                        f"{grp.pending} member runs never landed "
+                        f"{grp.pending} packed runs never landed "
                         f"(gather pool wedged?)")
             dt = time.perf_counter() - t_w
             agg.read_wait_s += dt
             reg.histogram("sched.stage.read_wait_seconds").observe(dt)
-            # the dispatch below is the buffer's last use here: once the
-            # group has landed nothing of this offload keeps it alive
-            pages, grp.pages = grp.pages, None
-            if not served:
+            if grp.packed is not None:
+                parts.append((grp.packed_items, grp.packed, False))
+                grp.packed = None
+                agg.stage_s += grp.stage_s
+                reg.histogram("sched.stage.staging_seconds").observe(
+                    grp.stage_s)
+            pieces = []
+            for items, pages, direct in parts:
+                lo = 0
+                for width in _pieces(len(items)):
+                    pieces.append((items[lo:lo + width],
+                                   pages[lo:lo + width], direct))
+                    lo += width
+            if not pieces:
                 continue
-            if grp.zero_copy:
-                pages = np.asarray(raw0).reshape(m_b, chunk_pages, page_elems)
-            agg.stage_s += grp.stage_s
-            reg.histogram("sched.stage.staging_seconds").observe(grp.stage_s)
-            put = None
+            grp_rows = sum(len(items) for items, _, _ in pieces)
+            grp.landing = len(pieces)
             slots.acquire()
-            t_d = time.perf_counter()
-            with _trace.span("stage.dispatch", chunks=len(served), rows=m_b,
-                             bytes=pages.nbytes):
-                if _trace.enabled():
-                    # the put the call would start itself, started here so
-                    # land() can record when it finished: the dispatcher
-                    # never waits for it
-                    put = [time.monotonic()]
-                    put.append(jp.put(pages))
-                    out = jp(put[1])
-                else:
-                    out = jp(pages)
-            del pages
-            dt = time.perf_counter() - t_d
-            agg.compute_s += dt
-            agg.dispatches += 1
-            reg.histogram("sched.stage.dispatch_seconds").observe(dt)
-            agg.batched += len(served)
-            agg.degraded += sum(1 for _, c in served if c.degraded)
-            # Materialize the batch output OFF the dispatcher: np.asarray on
-            # the lazy jax result blocks until XLA finishes, and paying that
-            # here would serialize group k's compute ahead of group k+1's
-            # read wait and dispatch — the pool thread eats the wait instead,
-            # then feeds the combiner its rows in one go. It runs ahead of
-            # queued staging copies, so the put is timed, and its slot
-            # freed, once the device is done and a pool thread is free,
-            # not once every queued copy is.
-            rows = [(row, pos_of[c.index]) for row, c in served]
+            for items, pages, direct in pieces:
+                jp = executable(len(items))
+                put = None
+                t_d = time.perf_counter()
+                with _trace.span("stage.dispatch", group=g, rows=len(items),
+                                 bytes=pages.nbytes):
+                    if _trace.enabled():
+                        # the put the call would start itself, started here
+                        # so land() can record when it finished: the
+                        # dispatcher never waits for it
+                        if not grp.put_t0:
+                            grp.put_t0 = time.monotonic()
+                        put = [jp.put(pages)]
+                        out = jp(put[0])
+                    else:
+                        out = jp(pages)
+                dt = time.perf_counter() - t_d
+                agg.compute_s += dt
+                agg.dispatches += 1
+                reg.histogram("sched.stage.dispatch_seconds").observe(dt)
+                reg.counter("sched.stage.rows_direct" if direct
+                            else "sched.stage.rows_packed").inc(len(items))
+                agg.batched += len(items)
+                agg.degraded += sum(1 for _, c in items if c.degraded)
+                # Materialize the batch output OFF the dispatcher: np.asarray
+                # on the lazy jax result blocks until XLA finishes, and
+                # paying that here would serialize piece k's compute ahead of
+                # the next piece's put — the pool thread eats the wait
+                # instead, then feeds the combiner its rows in one go. It
+                # runs ahead of queued packing, so the put is timed, and the
+                # group's slot freed, once the device is done and a pool
+                # thread is free, not once every queued copy is.
+                rows = [(row, pos_of[c.index])
+                        for row, (_, c) in enumerate(items)]
 
-            def land(out=out, rows=rows, put=put):
-                try:
-                    if put is not None:
-                        # pop: the pool must not keep the group in HBM
-                        put.pop().block_until_ready()
-                        _trace.event_complete(
-                            "stage.put", put[0], time.monotonic() - put[0],
-                            offload=cmd.cmd_id, chunks=len(rows))
-                    with _trace.span("stage.materialize", rows=len(rows)):
-                        if isinstance(out, tuple):
-                            bufs, ns = (np.asarray(v) for v in out)
-                            vals = {pos: (bufs[row], ns[row])
-                                    for row, pos in rows}
-                        else:
-                            o = np.asarray(out)
-                            vals = {pos: o[row] for row, pos in rows}
-                    combiner.feed(vals)
-                except BaseException as e:
-                    combiner.fail(e)
-                finally:
-                    slots.release()
+                def land(out=out, rows=rows, put=put, g=g, grp=grp,
+                         grp_rows=grp_rows):
+                    try:
+                        if put is not None:
+                            # pop: the pool must not keep the piece in HBM
+                            put.pop().block_until_ready()
+                            with grp.lock:
+                                grp.put_end = max(grp.put_end,
+                                                  time.monotonic())
+                        with _trace.span("stage.materialize", group=g,
+                                         rows=len(rows)):
+                            if isinstance(out, tuple):
+                                bufs, ns = (np.asarray(v) for v in out)
+                                vals = {pos: (bufs[row], ns[row])
+                                        for row, pos in rows}
+                            else:
+                                o = np.asarray(out)
+                                vals = {pos: o[row] for row, pos in rows}
+                        combiner.feed(vals)
+                    except BaseException as e:
+                        combiner.fail(e)
+                    finally:
+                        with grp.lock:
+                            grp.landing -= 1
+                            last = grp.landing == 0
+                        if last:
+                            if grp.put_end:
+                                # one put a group, as one group's bytes:
+                                # from its first piece's put until its
+                                # last piece's is ready
+                                _trace.event_complete(
+                                    "stage.put", grp.put_t0,
+                                    grp.put_end - grp.put_t0,
+                                    offload=cmd.cmd_id, group=g,
+                                    chunks=grp_rows)
+                            slots.release()
 
-            _gather_executor().submit(land, ahead=True)
+                _gather_executor().submit(land, ahead=True)
         if staged.groups:
             agg.insns += program.n_insns * agg.batched * (
                 stripe // self.pages_per_read)

@@ -102,8 +102,8 @@ class _GatherPool:
 
     Jobs run in submission order, except that an ``ahead`` job runs before
     every queued job that is not: a short job whose result the caller is
-    waiting for (a staged group landing its partial) must not queue behind
-    a backlog of staging memcpys.
+    waiting for (a dispatched piece landing its partial) must not queue
+    behind a backlog of packing copies.
     """
 
     def __init__(self, max_workers: int = 4):

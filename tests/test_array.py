@@ -728,19 +728,41 @@ def _assert_same_answer(want, got):
         assert np.array_equal(np.asarray(want), np.asarray(got))
 
 
-def _spy_stage_buffers(monkeypatch) -> list:
-    """(weak reference, nbytes) of every staging buffer the scheduler
-    allocates from here on."""
-    seen = []
-    real = OffloadScheduler._stage_on_land
+def _spy_pieces(monkeypatch, arr) -> dict:
+    """What the scheduler hands the device from here on: ``host`` holds
+    ``(is a view of a member's zone bytes, rows)`` for every batched call's
+    host pages, ``device`` a weak reference to every piece put to HBM,
+    ``packed`` the rows of every group's packed runs."""
+    from repro.core.vm import JittedProgram
+    seen = {"host": [], "device": [], "packed": []}
+    zones = [d._buf for d in arr.devices]
+    real_call, real_put = JittedProgram.__call__, JittedProgram.put
+    real_pack = OffloadScheduler._pack_on_land
 
-    def spy(grp, run, chunk_pages, page_elems):
-        if not any(ref() is grp.pages for ref, _ in seen):
-            seen.append((weakref.ref(grp.pages), grp.pages.nbytes))
-        real(grp, run, chunk_pages, page_elems)
+    def note(pages):
+        if isinstance(pages, np.ndarray) and pages.ndim == 3:
+            seen["host"].append(
+                (any(np.may_share_memory(pages, z) for z in zones),
+                 pages.shape[0]))
 
-    monkeypatch.setattr(OffloadScheduler, "_stage_on_land",
-                        staticmethod(spy))
+    def call(self, pages):
+        note(pages)
+        return real_call(self, pages)
+
+    def put(pages):
+        note(pages)
+        dev = real_put(pages)
+        seen["device"].append(weakref.ref(dev))
+        return dev
+
+    def pack(grp, runs, chunk_pages, page_elems):
+        seen["packed"].append(sum(len(r.items) for r in runs))
+        real_pack(grp, runs, chunk_pages, page_elems)
+
+    monkeypatch.setattr(JittedProgram, "__call__", call)
+    monkeypatch.setattr(JittedProgram, "put", staticmethod(put))
+    monkeypatch.setattr(OffloadScheduler, "_pack_on_land",
+                        staticmethod(pack))
     return seen
 
 
@@ -757,33 +779,73 @@ def _traced_offload(sched, program, **kw):
     return got, stats, dispatches, delta
 
 
-@pytest.mark.parametrize("mode,n,dead", [("raid0", 4, None), ("xor", 4, 0)],
-                         ids=["raid0", "xor-dead-member"])
+def _group_rows(dispatches) -> dict:
+    """Rows dispatched per group, from the ``stage.dispatch`` tags."""
+    rows: dict = {}
+    for e in dispatches:
+        rows[e["tags"]["group"]] = rows.get(e["tags"]["group"], 0) \
+            + e["tags"]["rows"]
+    return rows
+
+
+def _is_pow2(n: int) -> bool:
+    return n > 0 and not n & (n - 1)
+
+
+@pytest.mark.parametrize("mode,n,dead", [("raid0", 4, None), ("xor", 4, 0),
+                                         ("raid1", 4, None),
+                                         ("raid0", 3, None), ("xor", 4, None)],
+                         ids=["raid0", "xor-dead-member", "raid1", "raid0x3",
+                              "xor"])
 @pytest.mark.parametrize("program", CAPPED_PROGRAMS,
                          ids=[p.name for p in CAPPED_PROGRAMS])
 def test_stage_groups_capped_by_bytes(monkeypatch, program, mode, n, dead):
     """An extent whose prefetch-depth groups would pass the group budget
     streams through budget-sized groups with a ragged tail: same answers
-    as the oracle, one dispatch a group, no buffer over the budget, and the
-    clamp counted once."""
-    budget = 5 * ROW_BYTES                     # the widest power of two: 4
+    as the oracle, no group and no dispatch over the budget, power-of-two
+    pieces, the clamp counted once. A member's share of a group that is one
+    run of two or more chunks (raid0) goes to the device as a view of its
+    zone bytes; every other run (raid1's round-robin replicas, xor's runs
+    cut by parity rows) is packed into the group's one part, so a group
+    costs at most ``n × log2(64)`` dispatches whatever the layout."""
+    budget = 65 * ROW_BYTES                    # the widest power of two: 64
     monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES", budget)
-    data = int32_blocks(4 * 19 + 2, seed=31)   # 19 full chunks and a tail
-    arr = make_array(n, redundancy=mode, zone_kib=1024)
+    data = int32_blocks(4 * 200 + 2, seed=31)  # 200 full chunks and a tail
+    arr = make_array(n, redundancy=mode, num_zones=1, zone_kib=4096)
     arr.zone_append(0, data)
     if dead is not None:
         arr.set_offline(0, device=dead)
-    buffers = _spy_stage_buffers(monkeypatch)
+    seen = _spy_pieces(monkeypatch, arr)
     got, stats, dispatches, delta = _traced_offload(
         OffloadScheduler(arr), program)
     _assert_same_answer(run_oracle(program, data), got)
     m = stats.batched_chunks
-    assert {e["tags"]["rows"] for e in dispatches} == {4}
-    assert stats.n_dispatches == len(dispatches) == -(-m // 4) >= 3
-    assert m % 4                               # the tail group is padded
-    assert buffers and all(nb <= budget for _, nb in buffers)
+    per_group = _group_rows(dispatches)
+    assert sorted(per_group) == list(range(-(-m // 64))) and len(per_group) >= 2
+    assert sum(per_group.values()) == m and max(per_group.values()) == 64
+    assert m % 64                              # a ragged tail group
+    assert all(_is_pow2(e["tags"]["rows"]) for e in dispatches)
     assert all(e["tags"]["bytes"] <= budget for e in dispatches)
+    assert stats.n_dispatches == len(dispatches) == len(seen["host"])
     assert delta["sched.stage.groups_capped"] == 1
+    direct = sum(r for view, r in seen["host"] if view)
+    packed = sum(r for view, r in seen["host"] if not view)
+    assert direct == delta.get("sched.stage.rows_direct", 0)
+    assert packed == delta.get("sched.stage.rows_packed", 0)
+    assert direct + packed == m
+    # nothing is copied but the packed runs, one part a group at most
+    assert packed == sum(seen["packed"])
+    assert len(seen["packed"]) <= len(per_group)
+    pieces: dict = {}
+    for e in dispatches:
+        pieces[e["tags"]["group"]] = pieces.get(e["tags"]["group"], 0) + 1
+    assert max(pieces.values()) <= n * math.log2(64)
+    if mode == "raid0":                        # a share is one run of 2+
+        assert packed == 0 and seen["packed"] == []
+    elif mode == "raid1":                      # every run is one chunk
+        assert direct == 0 and packed == m
+    else:                                      # parity cuts every share
+        assert packed > 0
     assert (stats.degraded_reads > 0) == (dead is not None)
 
 
@@ -791,7 +853,8 @@ def test_stage_groups_capped_by_bytes(monkeypatch, program, mode, n, dead):
                                             (78, 3), (200, 2)])
 def test_stage_groups_under_budget_keep_prefetch_depth_rule(n_blocks, depth):
     """Under the group budget the width is the prefetch-depth rule's:
-    ``prefetch_depth`` groups, bucketed up to a power of two, at least 2."""
+    ``prefetch_depth`` groups, bucketed up to a power of two, at least 2;
+    each group goes out in power-of-two pieces and no pad rows."""
     data = int32_blocks(n_blocks, seed=32)
     arr = make_array(4, zone_kib=1024)
     arr.zone_append(0, data)
@@ -803,52 +866,61 @@ def test_stage_groups_under_budget_keep_prefetch_depth_rule(n_blocks, depth):
     m_b = max(2, 1 << math.ceil(math.log2(-(-m // min(depth, m)))))
     assert m_b * ROW_BYTES <= scheduler_mod._STAGE_GROUP_BYTES
     assert stats.batched_chunks == m
-    assert {e["tags"]["rows"] for e in dispatches} == {m_b}
-    assert stats.n_dispatches == len(dispatches) == -(-m // m_b)
+    per_group = _group_rows(dispatches)
+    assert per_group == {g: min(m_b, m - g * m_b)
+                         for g in range(-(-m // m_b))}
+    assert all(_is_pow2(e["tags"]["rows"]) and e["tags"]["rows"] <= m_b
+               for e in dispatches)
+    assert stats.n_dispatches == len(dispatches)
     assert delta.get("sched.stage.groups_capped", 0) == 0
 
 
 @pytest.mark.parametrize("capped", [False, True], ids=["depth", "capped"])
 def test_stage_buffer_released_once_its_group_landed(monkeypatch, capped):
-    """By the combine rendezvous every group has landed its partial, and
-    nothing of the offload still holds a group's staging buffer."""
+    """By the combine rendezvous every piece has landed its partial, and
+    nothing of the offload still holds a piece's device array."""
     if capped:
         monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES",
-                            4 * ROW_BYTES)
+                            9 * ROW_BYTES)
     arr = make_array(4, zone_kib=1024)
     arr.zone_append(0, int32_blocks(4 * 19, seed=33))
     sched = OffloadScheduler(arr)
     program = filter_count("int32", "gt", 0)
     sched.nvm_cmd_bpf_run(program, 0)          # compile outside the check
-    buffers = _spy_stage_buffers(monkeypatch)
+    seen = _spy_pieces(monkeypatch, arr)
     alive_at_rendezvous = []
     real_result = scheduler_mod._StagedCombiner.result
 
     def result(self):
         self._done.wait()
         deadline = time.monotonic() + 5.0
-        while (any(ref() is not None for ref, _ in buffers)
+        while (any(ref() is not None for ref in seen["device"])
                and time.monotonic() < deadline):
             gc.collect()
             time.sleep(0.01)
         alive_at_rendezvous.append(
-            sum(ref() is not None for ref, _ in buffers))
+            sum(ref() is not None for ref in seen["device"]))
         return real_result(self)
 
     monkeypatch.setattr(scheduler_mod._StagedCombiner, "result", result)
-    stats = sched.nvm_cmd_bpf_run(program, 0)
-    assert stats.n_dispatches == (5 if capped else 2)
-    assert len(buffers) == stats.n_dispatches
+    with trace.tracing(True):
+        stats = sched.nvm_cmd_bpf_run(program, 0)
+    trace.clear()
+    # groups of 16 and 3 (depth), or of 8, 8 and 3 (capped): runs of 4 or
+    # 2 chunks a member, then the tail's three one-chunk shares packed
+    assert stats.n_dispatches == (4 + 4 + 2 if capped else 4 + 2)
+    assert len(seen["device"]) == stats.n_dispatches
     assert alive_at_rendezvous == [0]
 
 
 @pytest.mark.parametrize("depth", [1, 2])
 def test_outstanding_groups_bounded_by_prefetch_depth(monkeypatch, depth):
-    """A group is dispatched only once all but ``prefetch_depth - 1`` of
-    the groups before it have materialized their partials, so HBM holds
-    at most that many group inputs and the pool at most that many lands."""
-    monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES", 2 * ROW_BYTES)
-    data = int32_blocks(4 * 12, seed=34)
+    """A group's first piece is dispatched only once every piece of all but
+    ``prefetch_depth - 1`` of the groups before it has materialized its
+    partial, so HBM holds at most that many groups' inputs and the pool at
+    most that many groups' lands."""
+    monkeypatch.setattr(scheduler_mod, "_STAGE_GROUP_BYTES", 9 * ROW_BYTES)
+    data = int32_blocks(4 * 40, seed=34)       # 5 groups of 8: 4 runs of 2
     arr = make_array(4, zone_kib=1024)
     arr.zone_append(0, data)
     sched = OffloadScheduler(arr, prefetch_depth=depth)
@@ -860,12 +932,19 @@ def test_outstanding_groups_bounded_by_prefetch_depth(monkeypatch, depth):
     events = trace.drain()
     trace.clear()
     assert int(got) == int(run_oracle(program, data))
-    starts = [e["ts"] for e in events if e["name"] == "stage.dispatch"]
-    ends = [e["ts"] + e["dur"] for e in events
-            if e["name"] == "stage.materialize"]
-    assert len(starts) == len(ends) == 6
-    for i, t in enumerate(sorted(starts)):
-        assert sum(end <= t for end in ends) >= i - depth + 1
+    starts: dict = {}
+    landed: dict = {}
+    for e in events:
+        g = e["tags"].get("group")
+        if e["name"] == "stage.dispatch":
+            starts[g] = min(starts.get(g, e["ts"]), e["ts"])
+        elif e["name"] == "stage.materialize":
+            landed.setdefault(g, []).append(e["ts"] + e["dur"])
+    assert sorted(starts) == sorted(landed) == list(range(5))
+    assert all(len(ends) == 4 for ends in landed.values())
+    for g, t in starts.items():
+        done = sum(max(ends) <= t for ends in landed.values())
+        assert done >= g - depth + 1
 
 
 def test_gather_pool_runs_ahead_jobs_before_queued_ones():

@@ -153,3 +153,17 @@ def test_q6_jit_runner_compiles_with_its_tail(one_chip):
                  date_lo=8766, date_hi=9131, disc_lo=5, disc_hi=7, qty_lt=24)
     assert 187_538 % vm.pages_per_step(187_538, 4 * PAGE)
     _compile_in_place(_build_program_runner(q6), 187_538, one_chip)
+
+
+@pytest.mark.parametrize("width", [512, 256, 64, 16])
+def test_array_piece_runner_compiles_in_place(one_chip, width):
+    """The array scan's pieces over four 64 KiB-striped members: a member's
+    512-chunk run of a full group, and the 256+64+16 of a ragged tail. Each
+    vmapped runner reads its piece where it lies, with no scratch copy."""
+    run = jax.vmap(_build_program_runner(
+        filter_count("int32", "gt", RAND_MAX // 2), batch=width))
+    spec = jax.ShapeDtypeStruct((width, 16, PAGE), jnp.int32,
+                                sharding=one_chip)
+    with offload_x64():
+        compiled = jax.jit(run).lower(spec).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < vm._SCAN_STEP_BYTES

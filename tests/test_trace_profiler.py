@@ -1,6 +1,7 @@
 """Program spans on the profiler's clock: span ids and parents, the
 ``jax.profiler`` bridge, gather-pool jobs that keep their offload, the
-timed host-to-HBM put and staging copy, and the executables' names.
+timed host-to-HBM put, the packing copy of short runs, and the
+executables' names.
 
 A CPU profiler trace carries ``TraceAnnotation``s on ``/host:CPU``, so the
 bridge is checked here without a chip."""
@@ -24,8 +25,9 @@ from repro.telemetry.metrics import registry
 from repro.zns import ZonedDevice
 
 BLOCK = 4096
-STRIPE = 64                  # 256 KiB chunks: each staging copy is real work
-N_FULL = 4                   # full chunks: two batch groups of two members
+STRIPE = 64                  # 256 KiB chunks: each packing copy is real work
+N_FULL = 8                   # full chunks: two batch groups of four
+GROUPS = 2
 TAIL = 2                     # a short tail chunk runs through execute_extent
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -39,18 +41,35 @@ def _fresh_trace():
     trace.clear()
 
 
-@pytest.fixture(scope="module")
-def sched():
-    """Two raid0 members; the zone holds four full chunks (two staged
-    groups, each copied from both members) and a tail chunk."""
+def _scheduler(redundancy: str):
     devs = [ZonedDevice(num_zones=1, zone_bytes=4 << 20, block_bytes=BLOCK)
             for _ in range(2)]
-    array = StripedZoneArray(devs, stripe_blocks=STRIPE)
+    array = StripedZoneArray(devs, stripe_blocks=STRIPE,
+                             redundancy=redundancy)
     rng = np.random.default_rng(7)
     n = (N_FULL * STRIPE + TAIL) * BLOCK // 4
     array.zone_append(0, rng.integers(-1000, 1000, n, dtype=np.int32))
     s = OffloadScheduler(array)
     s.nvm_cmd_bpf_run(PROGRAM, 0)          # compile outside every test
+    return s, array
+
+
+@pytest.fixture(scope="module")
+def sched():
+    """Two raid0 members; the zone holds eight full chunks (two groups of
+    four, each member's share one run of two, put to the device uncopied)
+    and a tail chunk."""
+    s, array = _scheduler("raid0")
+    yield s
+    s.close()
+    array.close()
+
+
+@pytest.fixture(scope="module")
+def mirrored():
+    """The same zone on a raid1 pair: replicas alternate by chunk, so
+    every run is one chunk and each group's four are packed by one copy."""
+    s, array = _scheduler("raid1")
     yield s
     s.close()
     array.close()
@@ -187,8 +206,8 @@ def test_program_spans_reach_the_profiler_nested_as_in_the_ring(
 
 
 # ----------------------------------------------------- the gather pool
-def test_gather_pool_spans_keep_their_offload_and_parent(sched):
-    events, ex = _traced_offload(sched)
+def test_gather_pool_spans_keep_their_offload_and_parent(mirrored):
+    events, ex = _traced_offload(mirrored)
     oid = ex["tags"]["offload"]
     mine = {e["id"] for e in events if e["tags"].get("offload") == oid}
     pool = _by_name(events, "gather.exec")
@@ -207,29 +226,55 @@ def test_gather_pool_spans_keep_their_offload_and_parent(sched):
 
 
 def test_stage_put_is_timed_once_a_group(sched):
+    """One put a group, though a group's two member runs are two pieces:
+    from its first piece's put, inside that piece's dispatch, until its
+    last piece's put was found ready, after that piece was dispatched."""
     events, ex = _traced_offload(sched)
     puts = _by_name(events, "stage.put")
     dispatches = _by_name(events, "stage.dispatch")
-    assert len(puts) == len(dispatches) == N_FULL // 2
+    assert len(dispatches) == GROUPS * 2
+    assert sorted(p["tags"]["group"] for p in puts) == list(range(GROUPS))
     for put in puts:
+        mine = [d for d in dispatches
+                if d["tags"]["group"] == put["tags"]["group"]]
+        assert put["tags"]["chunks"] == sum(d["tags"]["rows"] for d in mine)
         assert put["dur"] >= 0.0
         assert put["ts"] >= ex["ts"]
+        assert min(d["ts"] for d in mine) <= put["ts"]
+        assert put["ts"] + put["dur"] >= max(d["ts"] for d in mine)
     # the per-chunk tail puts its own pages, inside its serve_chunk
     (tput,) = _by_name(events, "tier.put")
     assert tput["tags"]["nbytes"] == TAIL * BLOCK
     assert _by_name(events, "tier.run")
 
 
-def test_stage_copy_sums_to_the_staging_histogram(sched):
+def test_stage_copy_sums_to_the_staging_histogram(mirrored):
     before = registry().snapshot()
     with trace.tracing(True):
-        sched.nvm_cmd_bpf_run(PROGRAM, 0)
+        mirrored.nvm_cmd_bpf_run(PROGRAM, 0)
     staged = registry().delta(before)["sched.stage.staging_seconds.sum"]
-    copies = _by_name(_settled_drain(want=N_FULL), "stage.copy")
-    assert len(copies) == N_FULL          # two groups, two member runs each
+    copies = _by_name(_settled_drain(want=GROUPS), "stage.copy")
+    assert len(copies) == GROUPS          # one packing copy a group
+    assert all(e["tags"]["rows"] == N_FULL // GROUPS for e in copies)
     total = sum(e["dur"] for e in copies)
     # the span encloses the timed copy and its bookkeeping, nothing more
     assert staged - 1e-9 <= total <= staged + 1e-3 * len(copies)
+
+
+def test_raid0_runs_reach_the_device_uncopied(sched):
+    """A member's share of a group, one run of two chunks, is put as the
+    view its read returned: no packing copy, nothing in the staging
+    histogram, two pieces a group and one ``stage.put`` a group."""
+    before = registry().snapshot()
+    events, _ = _traced_offload(sched)
+    delta = registry().delta(before)
+    assert not _by_name(events, "stage.copy")
+    assert delta.get("sched.stage.staging_seconds.count", 0) == 0
+    assert delta.get("sched.stage.rows_packed", 0) == 0
+    assert delta["sched.stage.rows_direct"] == N_FULL
+    dispatches = _by_name(events, "stage.dispatch")
+    assert [e["tags"]["rows"] for e in dispatches] == [2] * (GROUPS * 2)
+    assert len(_by_name(events, "stage.put")) == GROUPS
 
 
 def test_no_stage_staging_span_and_no_removed_offload_series(sched):
